@@ -160,13 +160,18 @@ def autotune_gemm():
     w = jax.random.normal(KEY, (K, N)) * 0.05
     x = jax.random.normal(jax.random.fold_in(KEY, 1), (M, K))
     qw = TQ.quantize(w, scheme="tile")
-    bm = AT.pick_block(M, 128)
+    # the Mosaic-legal candidates, with room along K to choose from
+    (m_ax, n_ax, (_, k_target, k_align)) = AT.gemm_axes(M, K, N,
+                                                        scheme="tile",
+                                                        group_size=g)
+    bm = AT.block_candidates(*m_ax)[0]
     best, best_us = None, float("inf")
-    for bn in AT.block_candidates(N, 256, g // 2, max_candidates=2):
-        for bk in AT.block_candidates(K, 128, 2, max_candidates=2):
+    for bn in AT.block_candidates(*n_ax, max_candidates=2):
+        for bk in AT.block_candidates(K, 4 * k_target, k_align,
+                                      max_candidates=2):
             fn = lambda xv: G.lut_dequant_gemm(
                 xv, qw["codes"], qw["scales"], qw["codebook"], scheme="tile",
-                group_size=g, bm=bm, bn=bn, bk=bk, interpret=ops.INTERPRET)
+                group_size=g, bm=bm, bn=bn, bk=bk)
             t = time_fn(fn, x, iters=2, warmup=1)
             emit(f"autotune.gemm_bm{bm}_bn{bn}_bk{bk}", t, "")
             if t < best_us:

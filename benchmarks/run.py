@@ -74,6 +74,9 @@ def run_snapshots(areas, record: bool, check: bool) -> int:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
                     help="comma list: quant,kernels,serving,roofline")

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import axis_size, shard_map
-
 
 def _quantize_int8(x):
     scale = jnp.max(jnp.abs(x)) / 127.0 + 1e-12
@@ -39,7 +37,7 @@ def _dequantize_int8(q, scale):
 def compressed_psum_local(x, axis: str, *, rs_dtype=jnp.float32):
     """Runs inside shard_map. x: any shape, identical on all shards of
     ``axis`` only in *shape*. Returns the full psum result (replicated)."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     flat = x.reshape(-1).astype(rs_dtype)
     pad = (-flat.shape[0]) % n
     flat = jnp.pad(flat, (0, pad))
@@ -63,7 +61,7 @@ def compressed_psum(tree, mesh, axis: str = "pod", *, rs_dtype=jnp.float32):
         return jax.tree.map(
             lambda x: compressed_psum_local(x, axis, rs_dtype=rs_dtype), args)
 
-    fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                        in_specs=(P(),), out_specs=P(), check_vma=False)
     return fn(tree)
 

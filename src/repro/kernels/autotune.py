@@ -8,15 +8,20 @@ reports against), and lets ``benchmarks/kernel_ablation.py`` overwrite the
 analytic choice with a *measured* one: its autotune section times the
 candidate set through the real kernels and records the winner in a cached
 per-shape table (``runs/autotune.json`` by default, override with
-``REPRO_AUTOTUNE_CACHE``).  Lookup order per shape key:
+``REPRO_AUTOTUNE_CACHE``), tagged with the ``device_kind`` it was timed
+on.  Lookup order per shape key:
 
 1. in-process memo;
-2. measured entry in the cache file;
+2. measured entry in the cache file, if it was recorded on the running
+   device kind (a CPU-timed winner never steers a TPU run);
 3. analytic roofline score over the candidate set.
 
-``REPRO_AUTOTUNE=0`` opts out entirely and restores the legacy fixed
-targets (still via :func:`pick_block`, so the divisibility contracts are
-enforced either way).
+Candidates are the block sizes Mosaic accepts: along each axis a divisor
+of the dim that is a multiple of the axis' alignment (8 sublanes, 128
+lanes, or what a derived scales block needs), or the whole dim.
+
+``REPRO_AUTOTUNE=0`` opts out entirely and restores the largest
+candidate per axis (still Mosaic-legal).
 
 Scoring is deterministic: ``max(flops/peak, bytes/bw)`` plus a per-grid-
 step launch overhead, with a hard penalty for blocks whose VMEM footprint
@@ -62,49 +67,27 @@ def reset() -> None:
 # ---------------------------------------------------------------------------
 
 
-def pick_block(n: int, target: int, multiple_of: int = 1) -> int:
-    """Largest divisor of ``n`` that is <= ``target`` and a multiple of
-    ``multiple_of``; falls back to ``n`` itself when no smaller divisor
-    qualifies.
-
-    Raises ``ValueError`` when no valid block exists at all — i.e. ``n``
-    itself violates ``multiple_of`` (this used to be returned silently,
-    truncating downstream BlockSpec shapes like the tile-scheme scales
-    block ``bn // (group_size // 2)``).
-    """
-    b = min(n, target)
-    while b > 1 and (n % b or b % multiple_of):
-        b -= 1
-    if b > 1:
-        return b
-    if n % multiple_of:
-        raise ValueError(
-            f"no valid block size for an axis of size {n}: blocks must "
-            f"divide {n} and be a multiple of {multiple_of} (target "
-            f"{target}), but {n} itself is not a multiple of {multiple_of}")
-    return n
-
-
-def block_candidates(n: int, target: int, multiple_of: int = 1,
+def block_candidates(n: int, target: int, align: int = 1,
                      max_candidates: int = 4) -> list[int]:
-    """Valid block sizes (divisors of ``n``, multiples of ``multiple_of``),
-    largest-first starting at ``min(n, target)``, at most
-    ``max_candidates``.  Always contains :func:`pick_block`'s choice; same
-    ``ValueError`` contract when no valid block exists."""
-    out = []
-    b = min(n, target)
-    while b >= 1 and len(out) < max_candidates:
-        if n % b == 0 and b % multiple_of == 0:
-            out.append(b)
-        b -= 1
-    if not out:
-        out = [pick_block(n, target, multiple_of)]  # n itself, or raises
-    return out
+    """Block sizes along an axis of size ``n`` that Mosaic accepts:
+    divisors of ``n`` that are multiples of ``align`` and <= ``target``,
+    largest first, at most ``max_candidates``; ``[n]`` when there are
+    none (a block spanning the whole dim is always legal)."""
+    out = [b for b in range(min(n, target), 0, -1)
+           if n % b == 0 and b % align == 0]
+    return out[:max_candidates] or [n]
 
 
 # ---------------------------------------------------------------------------
 # Cache file (measured entries recorded by benchmarks/kernel_ablation.py)
 # ---------------------------------------------------------------------------
+
+
+def device_kind() -> str:
+    """The running device's kind, the key measured entries are valid for."""
+    import jax
+
+    return jax.devices()[0].device_kind
 
 
 def _load_cache() -> dict:
@@ -120,11 +103,12 @@ def _load_cache() -> dict:
 
 
 def record(key: str, blocks: Sequence[int], us: float) -> None:
-    """Record a measured block choice for ``key`` in the cache file (and
-    the in-process view, so subsequent picks use it immediately)."""
+    """Record a measured block choice for ``key``, timed on the running
+    device kind, in the cache file (and the in-process view, so
+    subsequent picks use it immediately)."""
     entries = dict(_load_cache())
     entries[key] = {"blocks": [int(b) for b in blocks], "us": float(us),
-                    "source": "measured"}
+                    "source": "measured", "device_kind": device_kind()}
     path = cache_path()
     d = os.path.dirname(path)
     if d:
@@ -161,20 +145,22 @@ def _roofline_score(flops: float, hbm_bytes: float, grid_steps: int,
 
 def choose(key: str, axes: Sequence[tuple[int, int, int]],
            score_fn: Callable[[Sequence[int]], float]) -> tuple[int, ...]:
-    """Pick one block size per ``(n, target, multiple_of)`` axis.
+    """Pick one block size per ``(n, target, align)`` axis.
 
-    With autotuning off this is exactly the legacy per-axis
-    :func:`pick_block`.  Otherwise a measured cache entry for ``key``
-    wins; failing that, the lowest ``score_fn`` over the cartesian
-    candidate set (ties to the largest blocks)."""
+    With autotuning off this is the largest :func:`block_candidates`
+    entry per axis.  Otherwise a measured cache entry for ``key``
+    recorded on this device kind wins; failing that, the lowest
+    ``score_fn`` over the cartesian candidate set (ties to the largest
+    blocks)."""
     memo_key = (key, enabled())
     if memo_key in _MEMO:
         return _MEMO[memo_key]
     if not enabled():
-        blocks = tuple(pick_block(*a) for a in axes)
+        blocks = tuple(block_candidates(*a)[0] for a in axes)
     else:
         ent = _load_cache().get(key)
-        if ent and len(ent.get("blocks", ())) == len(axes):
+        if (ent and len(ent.get("blocks", ())) == len(axes)
+                and ent.get("device_kind") == device_kind()):
             blocks = tuple(int(b) for b in ent["blocks"])
         else:
             import itertools
@@ -248,15 +234,24 @@ def gemm_key(M: int, K: int, N: int, scheme: str, group_size: int) -> str:
     return f"gemm:{M}x{K}x{N}:{scheme}:g{group_size}"
 
 
+def gemm_axes(M: int, K: int, N: int, *, scheme: str,
+              group_size: int = 32) -> list[tuple[int, int, int]]:
+    """``(n, target, align)`` for ``lut_dequant_gemm``'s (bm, bn, bk).
+
+    x and out blocks need bm % 8 and bk % 128; the packed codes block
+    (bk, bn/2) needs bn % 256.  Tile-scheme scales reach the kernel as
+    column panels whose blocks are whole along lanes; common-scheme
+    scales blocks are (bk/g, bn), so bk % (8·g).  Any axis without such
+    a divisor takes its whole dim."""
+    bk_align = 128 if scheme == "tile" else 8 * group_size
+    return [(M, 128, 8), (N, 256, 256), (K, max(128, bk_align), bk_align)]
+
+
 def gemm_blocks(M: int, K: int, N: int, *, scheme: str,
                 group_size: int = 32) -> tuple[int, int, int]:
-    """(bm, bn, bk) for ``lut_dequant_gemm`` under the scheme's scale-
-    block divisibility constraints."""
-    if scheme == "tile":
-        mk, mn = 2, group_size // 2
-    else:
-        mk, mn = group_size, 2
-    axes = [(M, 128, 1), (N, 256, mn), (K, 128, mk)]
+    """(bm, bn, bk) for ``lut_dequant_gemm``: the Mosaic-legal candidates
+    of :func:`gemm_axes`, ranked by the roofline score."""
+    axes = gemm_axes(M, K, N, scheme=scheme, group_size=group_size)
 
     def score(bl):
         bm, bn, bk = bl
@@ -312,8 +307,9 @@ def dequant_key(R: int, H: int, D: int, mode: str) -> str:
 
 
 def dequant_rows(R: int, H: int, D: int, mode: str) -> int:
-    """Row-block size for ``lut_dequant_kv`` (token-slab dequant)."""
-    axes = [(R, 256, 1)]
+    """Row-block size for ``lut_dequant_kv`` (token-slab dequant): a
+    multiple of 8 sublanes, or all of R."""
+    axes = [(R, 256, 8)]
     slab_in = H * (D // 2 if mode == "q4" else D) + H * D // 8
     slab_out = H * D * 4
 

@@ -20,6 +20,13 @@ paper's Fig. 14 ablation baselines (FP16 polynomial exp2, FP32 exp).
 Grid: (B*Hq, nq, nkv), kv innermost; m/l/acc live in VMEM scratch.
 The table (64 KiB) sits in VMEM — 0.05% of a v5e core's ~128 MiB, the
 analogue of the paper's 0.8%-of-TCM budget.
+
+The kernel runs interpreted only.  Mosaic on TPU v5e refuses what Alg. 1
+is built from: fp16 vector loads, f32 -> fp16 casts and fp16 max
+reductions ("Unsupported element type for the selected reduction"), and
+a gather from a 32768-entry table (only 2-D gathers within one vreg
+exist: "Only 2D gather is supported").  Compiling it therefore raises
+(``FP16_LUT_REFUSED``) instead of substituting another softmax.
 """
 from __future__ import annotations
 
@@ -29,15 +36,20 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from repro.kernels.common import require_interpret, resolve_interpret
 
 NEG_CAP = -30000.0  # finite "-inf" in fp16 range; LUT(e^{-30000}) == 0
 
 LUT_SIZE = 32768
+
+
+# why compiling the fp16 / LUT-softmax recurrence raises
+FP16_LUT_REFUSED = ("Mosaic on TPU refuses fp16 vectors (loads, f32->fp16 "
+                    "casts, max reductions) and gathers from the "
+                    "32768-entry exp table (only in-vreg 2-D gathers are "
+                    "supported)")
 
 
 def build_exp_lut(dtype=jnp.float16) -> jnp.ndarray:
@@ -129,12 +141,16 @@ def _kernel(q_ref, k_ref, v_ref, lut_ref, o_ref, acc_ref, m_ref, l_ref,
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bkv",
                                              "interpret", "exp_mode"))
 def lut_softmax_attention(q, k, v, lut, *, causal: bool = True, bq: int = 128,
-                          bkv: int = 128, interpret: bool = True,
+                          bkv: int = 128, interpret: bool | None = None,
                           exp_mode: str = "lut"):
     """q: (BH, Sq, D) fp16; k, v: (BH, Skv, D) fp16 (kv heads pre-expanded).
 
     Returns (BH, Sq, D) fp16. GQA head mapping is done by the ops wrapper.
+    ``interpret=None`` follows the backend; compiling raises (see the
+    module docstring).
     """
+    interpret = resolve_interpret(interpret)
+    require_interpret(interpret, "lut_softmax_attention", FP16_LUT_REFUSED)
     BH, Sq, D = q.shape
     _, Skv, _ = k.shape
     bq, bkv = min(bq, Sq), min(bkv, Skv)

@@ -1,15 +1,13 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret=True`` everywhere by default: this container is CPU-only and
-interpret mode executes the kernel bodies in Python for correctness; on a
-real TPU set ``repro.kernels.ops.INTERPRET = False`` (or env
-``REPRO_PALLAS_INTERPRET=0``) and the same BlockSpecs compile to Mosaic.
+Every kernel follows the backend (``repro.kernels.common.resolve_interpret``):
+interpreted on CPU, where the tests check it against ``kernels/ref.py``,
+and compiled by Mosaic on TPU.
 """
 from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +18,6 @@ from repro.kernels import lut_softmax_attention as _attn
 from repro.kernels import paged_attention as _paged
 from repro.kernels import tile_quantize as _tq
 from repro.quant import tile_quant as TQ
-
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 # Kernel-dispatch recording hook (serving/profiling.KernelProfiler).
 # ``hook(name, flops, hbm_bytes)`` fires once per wrapper call with the
@@ -75,14 +71,6 @@ def exp_lut():
     return _EXP_LUT
 
 
-def _pick_block(n: int, target: int, multiple_of: int = 1) -> int:
-    """Largest divisor of n that is <= target and a multiple of
-    ``multiple_of`` (falls back to n itself).  Raises ``ValueError`` when
-    no valid block exists — i.e. ``n`` itself violates ``multiple_of``
-    (previously returned silently, truncating downstream BlockSpecs)."""
-    return _autotune.pick_block(n, target, multiple_of)
-
-
 def plan_lut_dequant_matmul(qw: dict, *, m: int, group_size: int = 32):
     """Resolve scheme inference and block-size selection once for a fixed
     (M, K, N) and return a callable ``x -> x @ dequant(qw)``.
@@ -102,7 +90,7 @@ def plan_lut_dequant_matmul(qw: dict, *, m: int, group_size: int = 32):
         record_op("lut_dequant_matmul", *_autotune.gemm_cost(m, K, N))
         return _gemm.lut_dequant_gemm(
             x, codes, scales, codebook, scheme=scheme,
-            group_size=group_size, bm=bm, bn=bn, bk=bk, interpret=INTERPRET)
+            group_size=group_size, bm=bm, bn=bn, bk=bk)
 
     return run
 
@@ -133,7 +121,7 @@ def flash_attention(q, k, v, *, causal: bool = True, exp_mode: str = "lut",
     record_op("flash_attention", *_autotune.attn_cost(B * Hq, Sq, Skv, D))
     o = _attn.lut_softmax_attention(
         qt, kt, vt, exp_lut(), causal=causal,
-        bq=bq_pick, bkv=bkv_pick, interpret=INTERPRET, exp_mode=exp_mode)
+        bq=bq_pick, bkv=bkv_pick, exp_mode=exp_mode)
     return o.reshape(B, Hq, Sq, D).transpose(0, 2, 1, 3).astype(q.dtype)
 
 
@@ -166,7 +154,7 @@ def paged_flash_decode(q, k_pool, v_pool, table, cache_len, *,
     lut = exp_lut() if exp_mode == "lut" else None
     fn = _paged.quant_paged_attention if quantized else _paged.paged_attention
     o = fn(qg, k_pool, v_pool, table, cache_len, lut, window=window,
-           softcap=softcap, interpret=INTERPRET, exp_mode=exp_mode)
+           softcap=softcap, exp_mode=exp_mode)
     return o.reshape(B, 1, Hq, D)
 
 
@@ -196,8 +184,7 @@ def lut_dequant_gather(gathered):
     out = _gemm.lut_dequant_kv(
         codes.reshape(r, *codes.shape[-2:]),
         scales.reshape(r, *scales.shape[-2:]),
-        get_codebook(Q4_CODEBOOK), mode=mode, gr=gr, gc=gc, br=br,
-        interpret=INTERPRET)
+        get_codebook(Q4_CODEBOOK), mode=mode, gr=gr, gc=gc, br=br)
     return out.reshape(*lead, codes.shape[-2], d)
 
 
@@ -207,7 +194,7 @@ def tile_quantize_op(w, *, group_size: int = 32):
     bk, bn = _autotune.quantize_blocks(K, N)
     record_op("tile_quantize", *_autotune.quantize_cost(K, N))
     codes, scales = _tq.tile_quantize(
-        w, group_size=group_size, bk=bk, bn=bn, interpret=INTERPRET)
+        w, group_size=group_size, bk=bk, bn=bn)
     from repro.quant.codebooks import get_codebook
 
     return {"codes": codes, "scales": scales, "codebook": get_codebook("q4_0")}

@@ -5,6 +5,12 @@ are produced on-device (e.g. checkpoint-load-time quantization of a trained
 model) so the fp weights never have to round-trip through HBM twice.
 Geometry matches ``quant.tile_quant.quantize(scheme='tile')``: (2, 16)
 groups, scale = absmax/8, codes packed two-per-byte along N.
+
+The kernel runs interpreted only; compiling it raises.  Mosaic refuses
+its fp16 scale output (f32 -> fp16 casts), its (bk/2, bn/16) scales
+block, and the lane-strided slices that pack two codes per byte.  No
+serving path calls it: ``quant.qlinear.quantize_model_params`` quantizes
+in XLA.
 """
 from __future__ import annotations
 
@@ -13,6 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.common import require_interpret, resolve_interpret
 
 
 def _kernel(w_ref, codes_ref, scales_ref, *, group_size: int):
@@ -33,8 +41,13 @@ def _kernel(w_ref, codes_ref, scales_ref, *, group_size: int):
 
 @functools.partial(jax.jit, static_argnames=("group_size", "bk", "bn", "interpret"))
 def tile_quantize(w, *, group_size: int = 32, bk: int = 128, bn: int = 256,
-                  interpret: bool = True):
-    """w: (K, N) -> (codes (K, N//2) uint8, scales (K//2, N//16) f16)."""
+                  interpret: bool | None = None):
+    """w: (K, N) -> (codes (K, N//2) uint8, scales (K//2, N//16) f16).
+    ``interpret=None`` follows the backend."""
+    interpret = resolve_interpret(interpret)
+    require_interpret(interpret, "tile_quantize",
+                      "Mosaic refuses f32 -> fp16 casts, its (bk/2, bn/16) "
+                      "scales block and lane-strided packing slices")
     K, N = w.shape
     bk, bn = min(bk, K), min(bn, N)
     assert K % bk == 0 and N % bn == 0

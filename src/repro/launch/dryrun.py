@@ -25,7 +25,6 @@ import jax
 
 from repro.configs.base import ALL_SHAPES, SHAPES_BY_NAME
 from repro.configs.registry import ASSIGNED_ARCHS, cells, get_config
-from repro.distributed.compat import cost_analysis_dict
 from repro.distributed.sharding import ParallelContext
 from repro.launch.mesh import make_production_mesh
 
@@ -101,7 +100,7 @@ def run_cell(arch: str, shape_name: str, par: ParallelContext,
     t_compile = time.time() - t0
 
     ma = compiled.memory_analysis()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     colls = parse_collectives(compiled.as_text())
 
     rec = {

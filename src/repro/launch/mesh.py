@@ -7,18 +7,17 @@ never touches jax device state — the dry-run sets
 from __future__ import annotations
 
 import jax
-
-from repro.distributed.compat import mesh_axis_types_kw
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n_data: int = 1, n_model: int = 1):
     """Small mesh over however many (host) devices exist — used by tests."""
     return jax.make_mesh((n_data, n_model), ("data", "model"),
-                         **mesh_axis_types_kw(2))
+                         axis_types=(AxisType.Auto,) * 2)

@@ -4,6 +4,8 @@ CPU-scale (real execution, reduced config):
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-1.5b --smoke \
       --method best_of_n --budget 8 --tasks 10 [--quantize] [--ckpt runs/ckpt]
 
+On a TPU the same command without ``--smoke`` serves the published widths;
+``chip_smoke.py`` at the repo root drives this module's functions that way.
 The production path is the same engine under the production mesh
 (launch/dryrun.py proves the serve_step lowers for every arch × shape).
 """
@@ -19,11 +21,12 @@ from repro.core import reward as R
 from repro.core.controller import TTSSpec, sweep
 from repro.data import tasks as T
 from repro.data.tokenizer import ByteTokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serving.engine import DecodeEngine
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -123,39 +126,52 @@ def main():
                     help="CI smoke: shrink tasks/budget/steps so the run "
                          "finishes in seconds while still exercising the "
                          "full serving path")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.dry:
         args.tasks = min(args.tasks, 2)
         args.budget = min(args.budget, 4)
         args.max_tokens = min(args.max_tokens, 12)
         args.beam_steps = min(args.beam_steps, 2)
         args.step_tokens = min(args.step_tokens, 8)
+    return args
 
+
+def load_model(args):
+    """(cfg, tokenizer, model) for ``--arch`` (``--smoke``: reduced)."""
     cfg = get_config(args.arch, smoke=args.smoke)
     tok = ByteTokenizer()
     if cfg.vocab_size < tok.vocab_size:
         cfg = cfg.with_(vocab_size=tok.vocab_size)
-    model = api.get_model(cfg)
+    return cfg, tok, api.get_model(cfg)
 
+
+def init_params(args, cfg, model):
+    """``--ckpt`` restored, or random weights from seed 0."""
     if args.ckpt:
         from repro.checkpoint.checkpointer import Checkpointer
 
         ckpt = Checkpointer(args.ckpt)  # params-only checkpoint dir
         params, _ = ckpt.restore(model.abstract_params(cfg))
-    else:
-        params = model.init_params(jax.random.key(0), cfg)
+        return params
+    # one compiled program: op by op, a 1.5B init took about a minute on
+    # one TPU v5e chip
+    return jax.jit(model.init_params, static_argnums=1)(jax.random.key(0),
+                                                        cfg)
 
-    if args.quantize:
-        from repro.quant.qlinear import quantize_model_params
 
-        params = quantize_model_params(params)
-        print("[serve] weights quantized: tile-group Q4_0 + Q8_0 down-proj")
+def quantize_params(args, params):
+    """Apply ``--quantize`` (identity without it)."""
+    if not args.quantize:
+        return params
+    from repro.quant.qlinear import quantize_model_params
 
-    if args.continuous and args.method == "self_consistency":
-        print(f"[serve] WARNING: --continuous routes best_of_n and "
-              f"beam_search through the slot scheduler; {args.method} "
-              f"uses the direct path")
+    params = quantize_model_params(params)
+    print("[serve] weights quantized: tile-group Q4_0 + Q8_0 down-proj")
+    return params
 
+
+def build_engine(args, cfg, params, tok):
+    """The engine ``args`` describe, and its prefix cache (or None)."""
     max_len = 256
     kv_kwargs = {}
     if args.kv_quant != "none" and not args.paged:
@@ -188,6 +204,59 @@ def main():
 
         prefix_cache = PrefixCache(
             engine.pool, capacity_blocks=args.cache_capacity or None)
+    return engine, prefix_cache
+
+
+def run_sweep(args, engine, tok, prefix_cache=None, *, tracer=None,
+              profiler=None, spec_decode=None) -> list:
+    """Serve ``args``' task set through ``controller.sweep``; on a paged
+    engine, fail unless the drained pool holds only the prefix cache's
+    pins."""
+    if args.fewshot:
+        tasks = T.shared_prefix_dataset(123, args.tasks,
+                                        n_shots=args.fewshot)
+    else:
+        tasks = T.gen_dataset(123, args.tasks)
+    scorer = R.OracleVerifier()
+    spec = TTSSpec(method=args.method, budget=args.budget,
+                   max_tokens=args.max_tokens, beam_width=args.beam_width,
+                   beam_expand=args.beam_expand, beam_steps=args.beam_steps,
+                   step_tokens=args.step_tokens)
+    sc = None
+    if spec_decode is not None:
+        from repro.serving.sampler import SamplerConfig
+
+        sc = SamplerConfig(greedy=True)
+    rows = sweep(engine, tok, tasks, [spec], jax.random.key(0), scorer,
+                 continuous=args.continuous, n_slots=args.slots,
+                 prefix_cache=prefix_cache, tracer=tracer,
+                 profiler=profiler, spec_decode=spec_decode, sc=sc)
+    if args.paged:
+        # leak check: after a full drain the pool holds only the prefix
+        # cache's pins — beam trees included (the pre-scheduler beam path
+        # used to leak every task's blocks here)
+        pinned = (prefix_cache.stats()["cached_blocks"]
+                  if prefix_cache is not None else 0)
+        in_use = engine.pool.blocks_in_use
+        if in_use != pinned:
+            raise SystemExit(
+                f"[serve] KV pool leak: {in_use} blocks still in use after "
+                f"drain (expected {pinned} cache-pinned)")
+        print(f"[serve] kv pool clean: {in_use} blocks in use after drain "
+              f"({pinned} cache-pinned)")
+    return rows
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parse_args(argv)
+    cfg, tok, model = load_model(args)
+    params = quantize_params(args, init_params(args, cfg, model))
+    if args.continuous and args.method == "self_consistency":
+        print(f"[serve] WARNING: --continuous routes best_of_n and "
+              f"beam_search through the slot scheduler; {args.method} "
+              f"uses the direct path")
+    engine, prefix_cache = build_engine(args, cfg, params, tok)
     tracer = None
     if args.trace or args.metrics:
         if not args.continuous:
@@ -226,25 +295,8 @@ def main():
         print(f"[serve] speculative decoding: k={args.spec_k} "
               f"{'draft=' + args.draft_model if args.draft_model else 'self-draft'}"
               f" (greedy sampling forced)")
-    if args.fewshot:
-        tasks = T.shared_prefix_dataset(123, args.tasks,
-                                        n_shots=args.fewshot)
-    else:
-        tasks = T.gen_dataset(123, args.tasks)
-    scorer = R.OracleVerifier()
-    spec = TTSSpec(method=args.method, budget=args.budget,
-                   max_tokens=args.max_tokens, beam_width=args.beam_width,
-                   beam_expand=args.beam_expand, beam_steps=args.beam_steps,
-                   step_tokens=args.step_tokens)
-    sc = None
-    if spec_decode is not None:
-        from repro.serving.sampler import SamplerConfig
-
-        sc = SamplerConfig(greedy=True)
-    rows = sweep(engine, tok, tasks, [spec], jax.random.key(0), scorer,
-                 continuous=args.continuous, n_slots=args.slots,
-                 prefix_cache=prefix_cache, tracer=tracer,
-                 profiler=profiler, spec_decode=spec_decode, sc=sc)
+    rows = run_sweep(args, engine, tok, prefix_cache, tracer=tracer,
+                     profiler=profiler, spec_decode=spec_decode)
     if args.trace:
         tracer.write_chrome_trace(args.trace)
         print(f"[serve] trace: {len(tracer.events)} events / "
@@ -263,19 +315,6 @@ def main():
               f"-> {args.profile}")
         for w in profiler.warnings:
             print(f"[serve] profile WARNING: {w}")
-    if args.paged:
-        # leak check: after a full drain the pool holds only the prefix
-        # cache's pins — beam trees included (the pre-scheduler beam path
-        # used to leak every task's blocks here)
-        pinned = (prefix_cache.stats()["cached_blocks"]
-                  if prefix_cache is not None else 0)
-        in_use = engine.pool.blocks_in_use
-        if in_use != pinned:
-            raise SystemExit(
-                f"[serve] KV pool leak: {in_use} blocks still in use after "
-                f"drain (expected {pinned} cache-pinned)")
-        print(f"[serve] kv pool clean: {in_use} blocks in use after drain "
-              f"({pinned} cache-pinned)")
     for r in rows:
         print(f"[serve] {r['method']} budget={r['budget']} "
               f"accuracy={r['accuracy']:.3f} "

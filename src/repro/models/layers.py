@@ -298,10 +298,12 @@ _PAGED_ATTN_IMPL = os.environ.get("REPRO_PAGED_ATTN", "xla")
 def set_paged_attention_impl(impl: str) -> str:
     """Select the decode-attention backend for paged KV caches.
 
-    ``"xla"`` (default): gather-then-attend fallback below.  ``"kernel"``:
-    fused Pallas block-table walk (``repro.kernels.paged_attention``).
-    ``"kernel_lut"``: same kernel with the fp16 LUT softmax (Alg. 1) fused
-    in.  Returns the previous impl so callers can restore it.  Engines jit
+    ``"xla"`` (default): gather-then-attend fallback below, and XLA
+    dequantization of the partial-prefill prefix gather.  ``"kernel"``:
+    fused Pallas block-table walk (``repro.kernels.paged_attention``), and
+    the prefix gather dequantized by ``lut_dequant_kv``.  ``"kernel_lut"``:
+    the same with the fp16 LUT softmax (Alg. 1) fused in (interpreter
+    only).  Returns the previous impl so callers can restore it.  Engines jit
     their step functions at construction time, so set this *before*
     building the engine (or via ``REPRO_PAGED_ATTN``).
     """
@@ -311,6 +313,12 @@ def set_paged_attention_impl(impl: str) -> str:
                          f"expected one of {PAGED_ATTN_IMPLS}")
     prev, _PAGED_ATTN_IMPL = _PAGED_ATTN_IMPL, impl
     return prev
+
+
+def paged_attention_impl() -> str:
+    """The selected paged-KV read backend (see
+    :func:`set_paged_attention_impl`)."""
+    return _PAGED_ATTN_IMPL
 
 
 def paged_decode_attention(

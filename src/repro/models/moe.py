@@ -24,8 +24,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.distributed.sharding import ParallelContext
 
-from repro.distributed.compat import shard_map
-
 
 def init_moe(key, cfg: ModelConfig, dtype) -> dict:
     m = cfg.moe
@@ -131,7 +129,7 @@ def moe_ffn(p: dict, x: jnp.ndarray, cfg: ModelConfig, par: ParallelContext = No
         es_in = es_out = P(None, None, None)  # fsdp-only: gathered per layer
     # checkpoint: the (E, C, d) dispatch/activation buffers are recomputed
     # in backward instead of saved — they dominate MoE training memory.
-    fn = shard_map(
+    fn = jax.shard_map(
         jax.checkpoint(local_fn),
         mesh=par.mesh,
         in_specs=(xs, ws, es_in, es_in, es_out),

@@ -27,9 +27,13 @@ from repro.quant import tile_quant as TQ
 _USE_PALLAS = False
 
 
-def use_pallas_kernels(flag: bool) -> None:
+def use_pallas_kernels(flag: bool) -> bool:
+    """Route quantized matmuls through the LUT-dequant GEMM kernel; returns
+    the previous setting.  Read while tracing, so set it before an engine
+    first runs."""
     global _USE_PALLAS
-    _USE_PALLAS = flag
+    prev, _USE_PALLAS = _USE_PALLAS, flag
+    return prev
 
 
 def is_quantized(leaf) -> bool:
@@ -79,7 +83,9 @@ def quantize_model_params(params, *, scheme: str = "tile", codebook: str = "q4_0
 
     Returns a new pytree in which quantized leaves are dicts
     {"codes", "scales"[, "codebook"]}.  Stacked (scanned) layer weights of
-    shape (L, K, N) are quantized per-layer via vmap.
+    shape (L, K, N) are quantized one layer at a time (``lax.map``): the
+    nearest-code search holds a (K, N, 16) temporary, which for all 28
+    layers of a 1.5B model at once would not fit one chip.
     """
     policy = policy or DEFAULT_POLICY
 
@@ -103,7 +109,7 @@ def quantize_model_params(params, *, scheme: str = "tile", codebook: str = "q4_0
             return leaf
         fn = q4 if sch == "q4" else q8
         for _ in range(leaf.ndim - 2):  # stacked layer and/or expert dims
-            fn = jax.vmap(fn)
+            fn = partial(jax.lax.map, fn)
         # NB: the codebook is broadcast across stacked dims so that
         # lax.scan over stacked layer params can slice it uniformly.
         return fn(leaf)

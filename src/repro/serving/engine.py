@@ -206,6 +206,8 @@ class DecodeEngine:
             self._spec_verify_impl, donate_argnums=(5, 6),
             static_argnames=("prefix_w", "stop_ids"))
         self._forced_jit = jax.jit(self._forced_step_impl)
+        self._forced_paged_jit = jax.jit(self._forced_step_paged_impl,
+                                         donate_argnums=(2, 3))
 
     @property
     def table_width(self) -> int:
@@ -242,9 +244,9 @@ class DecodeEngine:
         block-granular, so short cached prefixes stop paying attention
         FLOPs over the full ``max_len`` table width.  Quantized pools
         gather code+scale leaves and dequantize the (L, B, P, Hkv, D)
-        prefix view through the vlut16 dequant kernel
-        (``repro.kernels.ops.lut_dequant_gather`` — bit-identical to the
-        XLA ``dequantize_for_pool`` path it replaces).
+        prefix view — in XLA under the "xla" paged-attention impl, through
+        the vlut16 dequant kernel under the kernel impls
+        (``repro.kernels.ops.lut_dequant_gather``, bit-identical).
         """
         prefix = self._gather_prefix(table, pool_k, pool_v, cached_lens,
                                      prefix_w=prefix_w)
@@ -262,7 +264,11 @@ class DecodeEngine:
         forward.  Invalid slots (rows shorter than the bucket) are masked
         downstream by ``lens`` inside ``forward``."""
         from repro.kernels import ops as kops
+        from repro.models.layers import paged_attention_impl
+        from repro.serving.kv_quant import dequantize_for_pool
 
+        dequant = (dequantize_for_pool if paged_attention_impl() == "xla"
+                   else kops.lut_dequant_gather)
         bs = self.pool.block_size
         ptab = jax.lax.slice_in_dim(table, 0, prefix_w, axis=1)
 
@@ -272,7 +278,7 @@ class DecodeEngine:
                 return g.reshape(g.shape[0], g.shape[1], prefix_w * bs,
                                  *g.shape[4:])
 
-            return kops.lut_dequant_gather(jax.tree.map(leaf, pool))
+            return dequant(jax.tree.map(leaf, pool))
 
         return {"k": gather(pool_k), "v": gather(pool_v), "len": lens}
 
@@ -1149,18 +1155,31 @@ class DecodeEngine:
         return dataclasses.replace(state, cache=cache, cache_len=new_len,
                                    pending_logits=pending)
 
+    def _forced_step_paged_impl(self, params, state: GenState, pool_k,
+                                pool_v, tok):
+        st = self._forced_step_impl(
+            params, dataclasses.replace(state, cache={
+                "k": pool_k, "v": pool_v, "table": state.cache["table"]}),
+            tok)
+        return (dataclasses.replace(st, cache=state.cache), st.cache["k"],
+                st.cache["v"])
+
     def forced_step(self, state: GenState, tok) -> GenState:
         """Feed a *given* token per row (no sampling): the scheduler's
         draft-model engine consumes the target's already-committed token
-        before proposing its continuation.  Logprob/n_gen bookkeeping is
-        untouched — draft-side counts never reach scheduler metrics.
-        Dense layout only (the draft engine is dense; its whole state is
-        scratch that the next round resyncs)."""
-        if self.paged:
-            raise ValueError("forced_step supports the dense KV layout "
-                             "only (the speculative draft engine)")
-        return self._forced_jit(self.params, state,
-                                jnp.asarray(tok, jnp.int32))
+        before proposing its continuation, and teacher-forced numerics
+        checks compare the logits that follow.  Logprob/n_gen bookkeeping
+        is untouched — draft-side counts never reach scheduler metrics.
+        Paged: plans blocks first (:meth:`prepare_decode`) and writes the
+        token's KV into the pool in place."""
+        tok = jnp.asarray(tok, jnp.int32)
+        if not self.paged:
+            return self._forced_jit(self.params, state, tok)
+        state = self.prepare_decode(state)
+        st, pk, pv = self._forced_paged_jit(self.params, state, self.pool.k,
+                                            self.pool.v, tok)
+        self.pool.adopt(pk, pv)
+        return st
 
 
 # ---------------------------------------------------------------------------

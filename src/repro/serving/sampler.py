@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 from repro.distributed.sharding import ParallelContext
 
 
@@ -122,7 +121,7 @@ def distributed_sample(logits: jnp.ndarray, rng, sc: SamplerConfig,
         return _local_gumbel_max(lg, key, sc.temperature, "model", vps)
 
     batch_axes = par.batch_axes_for(logits.shape[0])
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=par.mesh,
         in_specs=(P(batch_axes, "model"), P()),
         out_specs=P(batch_axes),

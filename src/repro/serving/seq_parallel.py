@@ -30,8 +30,6 @@ from repro.configs.base import ModelConfig
 from repro.distributed.sharding import ParallelContext
 from repro.models import layers as L
 
-from repro.distributed.compat import shard_map
-
 
 def _seq_shard_attention(q, new_k, new_v, cache_k, cache_v, cache_len, window,
                          *, axis: str, softcap: float, ring_size: int = 0):
@@ -115,7 +113,7 @@ def seq_parallel_attention(q, new_k, new_v, cache_k, cache_v, cache_len,
     act4 = P(batch_ax, None, None, None)
     vec = P(batch_ax)
     cache_spec = P(batch_ax, axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda *a: _seq_shard_attention(*a, axis=axis,
                                         softcap=cfg.logit_softcap,
                                         ring_size=ring_size),

@@ -27,14 +27,13 @@ def test_compressed_psum_matches_psum():
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.distributed.compat import shard_map
         from repro.distributed.compression import compressed_psum_local
         mesh = jax.make_mesh((8,), ("pod",))
         x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8) / 7.0
         def local(v):
             return compressed_psum_local(v, "pod")
-        fn = shard_map(local, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                       check_vma=False)
+        fn = jax.shard_map(local, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                           check_vma=False)
         got = fn(x)
         want = x * 8  # psum of identical replicas
         rel = float(jnp.abs(got - want).max() / jnp.abs(want).max())
@@ -47,19 +46,18 @@ def test_compressed_psum_reduces_allreduce_bytes():
     run_with_devices("""
         import jax, jax.numpy as jnp, re
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.distributed.compat import shard_map
         from repro.distributed.compression import compressed_psum_local
         mesh = jax.make_mesh((8,), ("pod",))
         x = jnp.zeros((1024, 64), jnp.float32)
         sh = NamedSharding(mesh, P())
         plain = jax.jit(
-            shard_map(lambda v: jax.lax.psum(v, "pod"), mesh=mesh,
-                      in_specs=(P(),), out_specs=P(), check_vma=False),
+            jax.shard_map(lambda v: jax.lax.psum(v, "pod"), mesh=mesh,
+                          in_specs=(P(),), out_specs=P(), check_vma=False),
             in_shardings=(sh,)).lower(x).compile().as_text()
         comp = jax.jit(
-            shard_map(lambda v: compressed_psum_local(v, "pod"),
-                      mesh=mesh, in_specs=(P(),), out_specs=P(),
-                      check_vma=False),
+            jax.shard_map(lambda v: compressed_psum_local(v, "pod"),
+                          mesh=mesh, in_specs=(P(),), out_specs=P(),
+                          check_vma=False),
             in_shardings=(sh,)).lower(x).compile().as_text()
         def coll_bytes(txt):
             tot = 0

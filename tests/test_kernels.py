@@ -339,6 +339,22 @@ def test_lut_dequant_gather_bitwise(mode):
     assert ops.lut_dequant_gather(x) is x
 
 
+def test_f16_bits_decode_exactly_in_kernel_form():
+    """fp16 scales reach Mosaic kernels as bf16-typed bits; the in-kernel
+    decode must reproduce every fp16 value (normals, subnormals, zeros,
+    infinities) bit for bit, and NaN patterns as NaN."""
+    from repro.kernels.common import as_bf16_bits, f16_bits_to_f32
+
+    bits = jnp.arange(2**16, dtype=jnp.uint32).astype(jnp.uint16)
+    x16 = jax.lax.bitcast_convert_type(bits, jnp.float16)
+    got = np.asarray(f16_bits_to_f32(as_bf16_bits(x16)))
+    want = np.asarray(x16.astype(jnp.float32))
+    nan = np.isnan(want)
+    assert (np.isnan(got) == nan).all()
+    np.testing.assert_array_equal(got[~nan].view(np.uint32),
+                                  want[~nan].view(np.uint32))
+
+
 def test_plan_lut_dequant_matmul_hoists_python_work(monkeypatch):
     """plan() must match the one-shot wrapper bitwise and resolve scheme
     inference once, not per call."""
@@ -363,16 +379,17 @@ def test_plan_lut_dequant_matmul_hoists_python_work(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def test_pick_block_raises_on_impossible_constraint():
-    """_pick_block(n, ...) used to silently return n when n itself
-    violated multiple_of, truncating downstream BlockSpec shapes (e.g. the
-    tile-scheme scales block bn // (group_size // 2))."""
-    with pytest.raises(ValueError, match="multiple of 16"):
-        ops._pick_block(24, 256, multiple_of=16)
-    # legacy behavior everywhere a valid block exists
-    assert ops._pick_block(256, 128) == 128
-    assert ops._pick_block(48, 32, 16) == 16
-    assert ops._pick_block(7, 4) == 7  # prime: falls back to n
+def test_block_candidates_obey_mosaic_rule():
+    """Candidates are divisors that are multiples of the alignment, largest
+    first; an axis with none takes its whole dim (always legal), never a
+    block that would truncate the BlockSpec."""
+    from repro.kernels import autotune as AT
+
+    assert AT.block_candidates(1024, 256, 128) == [256, 128]
+    assert AT.block_candidates(8960, 256, 256) == [256]
+    assert AT.block_candidates(96, 256, 256) == [96]      # whole dim
+    assert AT.block_candidates(24, 256, 16) == [24]       # whole dim
+    assert AT.block_candidates(7, 4) == [1]
 
 
 def test_lut_attention_rejects_indivisible_blocks():
@@ -404,17 +421,21 @@ def test_lut_dequant_gemm_rejects_bad_shapes():
 
 
 def test_autotune_defaults_match_legacy_choices(tmp_path, monkeypatch):
-    """With no measured cache, the analytic roofline reproduces the old
-    fixed-target picks — autotuning must not churn kernel behavior.
-    (Point the cache at an empty path: a benchmark run in this checkout
-    may have recorded measured winners in runs/autotune.json, and those
-    legitimately override the analytic choice this test pins.)"""
+    """With no measured cache, the analytic roofline reproduces the
+    fixed-target picks wherever Mosaic's block rule allows them (the
+    common scheme's (bk/32, bn) scales block needs bk % 256) —
+    autotuning must not churn kernel behavior.  (Point the cache at an
+    empty path: a benchmark run in this checkout may have recorded
+    measured winners in runs/autotune.json, and those legitimately
+    override the analytic choice this test pins.)"""
     from repro.kernels import autotune as AT
 
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "empty.json"))
     AT.reset()
     assert AT.gemm_blocks(16, 1024, 1024, scheme="tile") == (16, 256, 128)
-    assert AT.gemm_blocks(8, 256, 512, scheme="common") == (8, 256, 128)
+    assert AT.gemm_blocks(8, 256, 512, scheme="common") == (8, 256, 256)
+    # qwen2.5-1.5b's FFN up-projection: Mosaic-legal tiles, not full width
+    assert AT.gemm_blocks(8, 1536, 8960, scheme="tile") == (8, 256, 128)
     assert AT.attn_blocks(8, 256, 256, 64) == (128, 128)
     assert AT.quantize_blocks(512, 1024) == (128, 256)
     assert AT.dequant_rows(48, 2, 32, "q8") == 48
@@ -440,4 +461,25 @@ def test_autotune_cache_roundtrip(tmp_path, monkeypatch):
     AT.reset()
     assert AT.gemm_blocks(16, 1024, 1024, scheme="tile") == (16, 256, 128)
     monkeypatch.delenv("REPRO_AUTOTUNE")
+    AT.reset()
+
+
+def test_autotune_ignores_entries_from_other_devices(tmp_path, monkeypatch):
+    """A measured winner applies only on the device kind it was timed on:
+    a CPU-timed entry must not steer a TPU run, nor the reverse."""
+    import json
+
+    from repro.kernels import autotune as AT
+
+    cache = tmp_path / "autotune.json"
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(cache))
+    AT.reset()
+    key = AT.gemm_key(16, 1024, 1024, "tile", 32)
+    AT.record(key, (16, 512, 256), 3.0)
+    entry = json.loads(cache.read_text())["entries"][key]
+    assert entry["device_kind"] == AT.device_kind()
+    entry["device_kind"] = "not " + entry["device_kind"]  # another device
+    cache.write_text(json.dumps({"version": 1, "entries": {key: entry}}))
+    AT.reset()
+    assert AT.gemm_blocks(16, 1024, 1024, scheme="tile") == (16, 256, 128)
     AT.reset()

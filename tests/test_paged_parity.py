@@ -195,3 +195,23 @@ def test_stop_ids_and_done_freezing_parity(dense_engine, trained_tiny,
                                np.asarray(sp.pending_logits), atol=ATOL)
     np.testing.assert_array_equal(np.asarray(sd.cache_len),
                                   np.asarray(sp.cache_len))
+
+
+def test_forced_step_parity(dense_engine, trained_tiny, tiny_cfg, tok):
+    """Teacher forcing on the paged layout: feeding given tokens writes
+    their KV into pool blocks and yields the dense engine's logits; the
+    rows' blocks all return to the pool on release."""
+    paged = make_paged(trained_tiny, tiny_cfg, tok, block_size=4,
+                       n_blocks=64)
+    toks, lens = _draw_prompts(7, batch=3)
+    forced = np.random.default_rng(7).integers(3, 300, size=(3, 6))
+    sd, sp = dense_engine.prefill(toks, lens), paged.prefill(toks, lens)
+    for t in range(forced.shape[1]):
+        sd = dense_engine.forced_step(sd, forced[:, t])
+        sp = paged.forced_step(sp, forced[:, t])
+        np.testing.assert_allclose(np.asarray(sd.pending_logits),
+                                   np.asarray(sp.pending_logits), atol=ATOL)
+    np.testing.assert_array_equal(np.asarray(sp.cache_len),
+                                  np.asarray(lens) + forced.shape[1])
+    paged.release_rows(sp, [0, 1, 2])
+    assert paged.pool.blocks_in_use == 0
