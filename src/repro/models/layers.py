@@ -9,9 +9,16 @@ so that prefill_32k / train_4k never materialize S×S score matrices in the
 lowered HLO.  The Pallas LUT-softmax kernel (`repro.kernels.
 lut_softmax_attention`) is the TPU hot path with identical semantics; this
 file is the XLA path used for dry-runs and CPU execution.
+
+Each layer of a program runs under a ``jax.named_scope`` (``attention``
+with its ``kv_write``, ``mlp``; ``lm_head`` and ``sampler`` outside the
+layer loop), so its operations carry the scope in their HLO op names and
+a profiler trace can split device time by layer.  Scopes change only
+metadata: the computation is the same.
 """
 from __future__ import annotations
 
+import functools
 import math
 import os
 from functools import partial
@@ -21,6 +28,19 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+
+
+
+def scoped(name: str):
+    """Decorator: run the function under ``jax.named_scope(name)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
 
 # ---------------------------------------------------------------------------
 # Initialization helpers
@@ -428,6 +448,7 @@ def init_attention(key, cfg: ModelConfig, dtype) -> dict:
     }
 
 
+@scoped("attention")
 def attention_block(
     p: dict,
     x: jnp.ndarray,
@@ -532,8 +553,9 @@ def attention_block(
                 lambda p, x: p.at[blk, off].set(x.astype(p.dtype)),
                 pool, payload)
 
-        ck = upd(cache["k"], k)
-        cv = upd(cache["v"], v)
+        with jax.named_scope("kv_write"):
+            ck = upd(cache["k"], k)
+            cv = upd(cache["v"], v)
         o = paged_decode_attention(q, ck, cv, table=table,
                                    cache_len=cache_len, window=window,
                                    softcap=cfg.logit_softcap)
@@ -549,8 +571,9 @@ def attention_block(
             b_idx = jnp.arange(B)
             return cache_arr.at[b_idx, idx].set(new_row[:, 0].astype(cache_arr.dtype))
 
-        ck = upd(cache["k"], k)
-        cv = upd(cache["v"], v)
+        with jax.named_scope("kv_write"):
+            ck = upd(cache["k"], k)
+            cv = upd(cache["v"], v)
         o = decode_attention(q, ck, cv, cache_len=cache_len, window=window,
                              softcap=cfg.logit_softcap, ring=ring)
         new_kv = (ck, cv)
@@ -573,6 +596,7 @@ def init_swiglu(key, d: int, f: int, dtype) -> dict:
     }
 
 
+@scoped("mlp")
 def swiglu(p: dict, x: jnp.ndarray) -> jnp.ndarray:
     return linear(p["down"], jax.nn.silu(linear(p["gate"], x)) * linear(p["up"], x))
 
@@ -585,6 +609,7 @@ def init_mlp(key, d: int, f: int, dtype) -> dict:
     }
 
 
+@scoped("mlp")
 def mlp(p: dict, x: jnp.ndarray) -> jnp.ndarray:
     return linear(p["fc2"], jax.nn.gelu(linear(p["fc1"], x)))
 

@@ -23,6 +23,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.distributed.sharding import ParallelContext
+from repro.models.layers import scoped
 
 
 def init_moe(key, cfg: ModelConfig, dtype) -> dict:
@@ -90,6 +91,7 @@ def _dispatch_compute_combine(x_flat, weights, ids, experts, m: MoEConfig, axis_
     return y
 
 
+@scoped("mlp")
 def moe_ffn(p: dict, x: jnp.ndarray, cfg: ModelConfig, par: ParallelContext = None):
     """x: (B, S, d) -> (y (B, S, d), aux_loss scalar)."""
     m = cfg.moe

@@ -168,13 +168,15 @@ def forward(params, tokens, cfg: ModelConfig, par: ParallelContext = None,
                (params["layers"], windows, prefix["k"], prefix["v"]))
     (x, aux), kvs = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
                                  scan_xs)
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    if logit_positions is not None:
-        # gather the true last position per sequence before the (large)
-        # lm_head matmul — avoids materializing (B, S, V) logits in prefill
-        x = x[jnp.arange(B), logit_positions]
-    head = params["embedding"] if cfg.tie_embeddings else params["lm_head"]
-    logits = L.lm_logits(head, x, cfg.logit_softcap)
+    with jax.named_scope("lm_head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        if logit_positions is not None:
+            # gather the true last position per sequence before the (large)
+            # lm_head matmul — avoids materializing (B, S, V) logits in
+            # prefill
+            x = x[jnp.arange(B), logit_positions]
+        head = params["embedding"] if cfg.tie_embeddings else params["lm_head"]
+        logits = L.lm_logits(head, x, cfg.logit_softcap)
     return logits, kvs, aux
 
 
@@ -217,6 +219,7 @@ def _quantize_tokens(pool, kvs):
     return quantize_for_pool(kvs, pool)
 
 
+@L.scoped("kv_write")
 def _scatter_prefill_blocks(pool, kvs, table, block_size: int):
     """Write prefill KV (L, B, S, Hkv, D) into pool blocks via the table.
 
@@ -243,6 +246,7 @@ def _scatter_prefill_blocks(pool, kvs, table, block_size: int):
     return jax.tree.map(leaf, pool, kvs)
 
 
+@L.scoped("kv_write")
 def _scatter_suffix_blocks(pool, kvs, table, block_size: int, start):
     """Write suffix KV (L, B, S, Hkv, D) into pool blocks at a per-row
     positional offset: row b's token j lands at global position
@@ -366,9 +370,10 @@ def decode_step(params, tokens, cache, cache_len, cfg: ModelConfig,
 
     x, (nk, nv) = jax.lax.scan(
         body, x, (params["layers"], windows, cache["k"], cache["v"]))
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    head = params["embedding"] if cfg.tie_embeddings else params["lm_head"]
-    logits = L.lm_logits(head, x[:, 0], cfg.logit_softcap)
+    with jax.named_scope("lm_head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        head = params["embedding"] if cfg.tie_embeddings else params["lm_head"]
+        logits = L.lm_logits(head, x[:, 0], cfg.logit_softcap)
     new_cache = {"k": nk, "v": nv}
     if table is not None:
         new_cache["table"] = table

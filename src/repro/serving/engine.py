@@ -98,7 +98,7 @@ from repro.serving.kv_pool import KVPool, OutOfBlocks, blocks_for
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.profiling import NULL_PROFILE_METRICS
 from repro.serving.sampler import SamplerConfig, logprobs_of, sample
-from repro.serving.telemetry import RequestLatency, Tracer, percentile
+from repro.serving.telemetry import RequestLatency, Tracer, percentile, phase
 
 
 @dataclass
@@ -280,7 +280,8 @@ class DecodeEngine:
 
             return dequant(jax.tree.map(leaf, pool))
 
-        return {"k": gather(pool_k), "v": gather(pool_v), "len": lens}
+        with jax.named_scope("attention"):  # attention's paged KV read
+            return {"k": gather(pool_k), "v": gather(pool_v), "len": lens}
 
     def prefill(self, tokens: jnp.ndarray, lengths: Optional[jnp.ndarray] = None,
                 embeddings=None, *, cached_table=None,
@@ -315,41 +316,38 @@ class DecodeEngine:
         B, S = tokens.shape
         if lengths is None:
             lengths = jnp.full((B,), S, jnp.int32)
-        tr = self.tracer
-        t0 = tr.now() if tr is not None else 0.0
-        prof = self.profiler
-        pt0 = prof.phase_begin("prefill") if prof is not None else 0.0
-        if cached_table is not None:
-            if not self.paged:
-                raise ValueError(
-                    "cached-prefix prefill requires a paged engine "
-                    "(DecodeEngine(paged=True))")
-            if embeddings is not None:
-                raise NotImplementedError(
-                    "cached-prefix prefill does not support modality-stub "
-                    "embeddings")
-            st = self._prefill_with_prefix(tokens, lengths, cached_table,
-                                           cached_lens)
-        elif self.paged:
-            st = self._prefill_paged(tokens, lengths, embeddings)
-        else:
-            logits, cache = self._prefill_jit(self.params, tokens, lengths,
-                                              embeddings)
-            st = GenState(
-                cache=cache,
-                cache_len=lengths.astype(jnp.int32),
-                pending_logits=logits.astype(jnp.float32),
-                done=jnp.zeros((B,), bool),
-                logprob_sum=jnp.zeros((B,), jnp.float32),
-                n_gen=jnp.zeros((B,), jnp.int32),
-            )
-        if prof is not None:
-            # sampled: blocks on the new state's logits so the wall spans
-            # the device work this prefill dispatched
-            prof.phase_end("prefill", pt0, outputs=st.pending_logits)
-        if tr is not None:
-            tr.span("prefill", t0, batch=int(B),
-                    cached=cached_table is not None)
+        with phase(self.tracer, "prefill", batch=int(B),
+                   cached=cached_table is not None):
+            prof = self.profiler
+            pt0 = prof.phase_begin("prefill") if prof is not None else 0.0
+            if cached_table is not None:
+                if not self.paged:
+                    raise ValueError(
+                        "cached-prefix prefill requires a paged engine "
+                        "(DecodeEngine(paged=True))")
+                if embeddings is not None:
+                    raise NotImplementedError(
+                        "cached-prefix prefill does not support "
+                        "modality-stub embeddings")
+                st = self._prefill_with_prefix(tokens, lengths, cached_table,
+                                               cached_lens)
+            elif self.paged:
+                st = self._prefill_paged(tokens, lengths, embeddings)
+            else:
+                logits, cache = self._prefill_jit(self.params, tokens,
+                                                  lengths, embeddings)
+                st = GenState(
+                    cache=cache,
+                    cache_len=lengths.astype(jnp.int32),
+                    pending_logits=logits.astype(jnp.float32),
+                    done=jnp.zeros((B,), bool),
+                    logprob_sum=jnp.zeros((B,), jnp.float32),
+                    n_gen=jnp.zeros((B,), jnp.int32),
+                )
+            if prof is not None:
+                # sampled: blocks on the new state's logits so the wall
+                # spans the device work this prefill dispatched
+                prof.phase_end("prefill", pt0, outputs=st.pending_logits)
         return st
 
     def _prefill_with_prefix(self, tokens, lengths, cached_table,
@@ -700,8 +698,9 @@ class DecodeEngine:
     def _step_core(self, params, state: GenState, cache_in, rng,
                    sc: SamplerConfig, stop_ids: tuple, row_stops=None):
         stop_ids = tuple(stop_ids) or (self.eos_id,)
-        tok = sample(state.pending_logits, rng, sc)
-        lp = logprobs_of(state.pending_logits, tok)
+        with jax.named_scope("sampler"):
+            tok = sample(state.pending_logits, rng, sc)
+            lp = logprobs_of(state.pending_logits, tok)
         tok = jnp.where(state.done, self.pad_id, tok).astype(jnp.int32)
         new_done = state.done
         for s in stop_ids:
@@ -776,29 +775,27 @@ class DecodeEngine:
         Under the default "xla" impl the exact path is the production
         path, so the comparison must be exact."""
         prof = self.profiler
+        tr = self.tracer
         if self.paged:
-            tr = self.tracer
-            if tr is not None:
-                t0 = tr.now()
-                state = self.prepare_decode(state)
-                tr.span("plan", t0)  # CoW/alloc host planning
-            else:
+            with phase(tr, "plan"):  # CoW/alloc host planning
                 state = self.prepare_decode(state)
             if canary:
                 self.last_canary_logits = self._canary_step(
                     state, rng, row_stops, sc, tuple(stop_ids))
             pt0 = prof.phase_begin("decode") if prof is not None else 0.0
-            st, tok, pk, pv = self._step_paged_jit(
-                self.params, state, self.pool.k, self.pool.v, rng,
-                row_stops, sc=sc, stop_ids=tuple(stop_ids))
+            with phase(tr, "dispatch"):
+                st, tok, pk, pv = self._step_paged_jit(
+                    self.params, state, self.pool.k, self.pool.v, rng,
+                    row_stops, sc=sc, stop_ids=tuple(stop_ids))
             if prof is not None:
                 prof.phase_end("decode", pt0,
                                outputs=(tok, st.pending_logits))
             self.pool.adopt(pk, pv)
             return st, tok
         pt0 = prof.phase_begin("decode") if prof is not None else 0.0
-        st, tok = self._step_jit(self.params, state, rng, row_stops, sc=sc,
-                                 stop_ids=tuple(stop_ids))
+        with phase(tr, "dispatch"):
+            st, tok = self._step_jit(self.params, state, rng, row_stops,
+                                     sc=sc, stop_ids=tuple(stop_ids))
         if prof is not None:
             prof.phase_end("decode", pt0, outputs=(tok, st.pending_logits))
         return st, tok
@@ -1046,11 +1043,8 @@ class DecodeEngine:
                              "(DecodeEngine(paged=True))")
         W = int(xs.shape[1])
         stop_ids = tuple(stop_ids) or (self.eos_id,)
-        tr = self.tracer
-        t0 = tr.now() if tr is not None else 0.0
-        state = self.prepare_decode(state, W, clamp=True)
-        if tr is not None:
-            tr.span("plan", t0)
+        with phase(self.tracer, "plan"):
+            state = self.prepare_decode(state, W, clamp=True)
         bs = self.pool.block_size
         clen_h, done_h = (np.asarray(a) for a in jax.device_get(
             (state.cache_len, state.done)))
@@ -1061,19 +1055,18 @@ class DecodeEngine:
         top = int(clen_h[live].max()) if live.any() else 1
         prefix_w = max(1, -(-top // bs))
         prof = self.profiler
-        t1 = tr.now() if tr is not None else 0.0
-        pt0 = prof.phase_begin("spec_verify") if prof is not None else 0.0
-        st, commit, pk, pv = self._spec_verify_jit(
-            self.params, state, jnp.asarray(xs, jnp.int32),
-            jnp.asarray(n_prop, jnp.int32), row_stops, self.pool.k,
-            self.pool.v, prefix_w=prefix_w, stop_ids=stop_ids)
-        if prof is not None:
-            prof.phase_end("spec_verify", pt0,
-                           outputs=(commit, st.pending_logits))
-        self.pool.adopt(pk, pv)
-        commit_h = np.asarray(jax.device_get(commit))
-        if tr is not None:
-            tr.span("spec_verify", t1, batch=int(xs.shape[0]), width=W)
+        with phase(self.tracer, "spec_verify", batch=int(xs.shape[0]),
+                   width=W):
+            pt0 = prof.phase_begin("spec_verify") if prof is not None else 0.0
+            st, commit, pk, pv = self._spec_verify_jit(
+                self.params, state, jnp.asarray(xs, jnp.int32),
+                jnp.asarray(n_prop, jnp.int32), row_stops, self.pool.k,
+                self.pool.v, prefix_w=prefix_w, stop_ids=stop_ids)
+            if prof is not None:
+                prof.phase_end("spec_verify", pt0,
+                               outputs=(commit, st.pending_logits))
+            self.pool.adopt(pk, pv)
+            commit_h = np.asarray(jax.device_get(commit))
         return st, commit_h
 
     def trim_rows(self, state: GenState, rows) -> GenState:
@@ -1609,8 +1602,8 @@ class ContinuousScheduler:
         # engine-level prefill/plan spans land in the same trace.  The
         # tracer's injectable clock also drives the per-step wall_s
         # measurement, keeping latency tests deterministic.
+        self._tracer: Optional[Tracer] = None
         self.tracer = tracer
-        engine.tracer = tracer
         # roofline/canary profiler (profiling.KernelProfiler), same
         # ownership discipline as the tracer: constructing a scheduler
         # (re)binds the engine's profiler slot and installs the kernel
@@ -1685,6 +1678,22 @@ class ContinuousScheduler:
             # blocks, and this is the number a byte-budgeted operator sizes
             self._block_bytes = engine.pool.block_bytes()
             self.metrics.kv_quant = engine.pool.mode
+
+    @property
+    def tracer(self) -> Optional[Tracer]:
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer: Optional[Tracer]) -> None:
+        """Bind ``tracer`` to this scheduler and its engine.  A bound
+        tracer records garbage collections; the tracer it replaces, here
+        or in the engine, stops."""
+        for old in (self._tracer, self.engine.tracer):
+            if old is not None and old is not tracer:
+                old.unwatch_gc()
+        if tracer is not None:
+            tracer.watch_gc()
+        self._tracer = self.engine.tracer = tracer
 
     # -- submission ----------------------------------------------------------
     def submit(self, req: Request):
@@ -2205,13 +2214,10 @@ class ContinuousScheduler:
         tr = self.tracer
         lp, ng = (np.asarray(a) for a in jax.device_get(
             (self.state.logprob_sum, self.state.n_gen)))
-        if tr is not None:
-            t0 = tr.now()
-        scores = np.asarray(
-            spec.score([list(t) for t in run.tokens], lp[rows], ng[rows]),
-            np.float64).ravel()
-        if tr is not None:
-            tr.span("prm", t0, step=self.step_count, candidates=len(rows))
+        with phase(tr, "prm", self.step_count, candidates=len(rows)):
+            scores = np.asarray(
+                spec.score([list(t) for t in run.tokens], lp[rows], ng[rows]),
+                np.float64).ravel()
         self.metrics.prm_batches += 1
         self.metrics.prm_candidates += len(rows)
         # stable sort: ties keep the lowest lane index, matching the
@@ -2250,13 +2256,9 @@ class ContinuousScheduler:
         tr = self.tracer
         final = spec.final_score or spec.score
         krows = [rows[int(k)] for k in keep]
-        if tr is not None:
-            t0 = tr.now()
-        final_scores = np.asarray(
-            final(survivors, lp[krows], ng[krows]), np.float64).ravel()
-        if tr is not None:
-            tr.span("prm", t0, step=self.step_count,
-                    candidates=len(survivors))
+        with phase(tr, "prm", self.step_count, candidates=len(survivors)):
+            final_scores = np.asarray(
+                final(survivors, lp[krows], ng[krows]), np.float64).ravel()
         self.metrics.prm_batches += 1
         self.metrics.prm_candidates += len(survivors)
         if self.cache is not None:
@@ -2487,77 +2489,107 @@ class ContinuousScheduler:
         driving ``step_once`` directly — controller loops, tests — get
         real ``wall_s``/throughput numbers: each step's host time lands
         in ``StepRecord.wall_s`` and accumulates into
-        ``metrics.wall_s``."""
+        ``metrics.wall_s``.
+
+        With a tracer the step records the span tree of
+        :mod:`repro.serving.telemetry`: ``step`` ⊃ {``admit``, ``decode``
+        ⊃ {``plan``, ``dispatch``, ``sync``}, ``post``}; an idle step
+        records its ``admit`` alone."""
         tr = self.tracer
         prof = self.profiler
         t_wall = self._clock()
+        s = self.step_count
+        with phase(tr, "step", s) as step_phase:
+            if prof is not None:
+                prof.begin_step()
+            with phase(tr, "admit", s) as ph:
+                admitted, prefill_tokens = self._admit()
+                ph.set(admitted=admitted, prefill_tokens=prefill_tokens)
+            live = [i for i, slot in enumerate(self.slots) if slot is not None]
+            if not live:
+                step_phase.cancel()
+                return False
+            if self._draft is not None:
+                self._sync_draft_admissions(live)
+            for i in live:
+                if self.slots[i].first_decode_step < 0:
+                    self.slots[i].first_decode_step = s
+            canary = (prof is not None and self.paged and prof.want_canary())
+            # speculative rounds need greedy sampling (acceptance compares
+            # argmaxes) and skip canary steps, whose exact-path replica is
+            # defined over the single-token decode step
+            spec_round = (self.spec is not None and sc.greedy and self.paged
+                          and not canary)
+            spec_out = None
+            # the decode span ends after the sync: the host-visible latency
+            # of this decode step
+            with phase(tr, "decode", s) as dec:
+                while True:
+                    try:
+                        if spec_round:
+                            spec_out = self._spec_step(rng, sc)
+                        if spec_out is None:
+                            self.state, toks = self.engine.step(
+                                self.state, rng, sc, stop_ids=self.stop_ids,
+                                row_stops=self._row_stops(), canary=canary)
+                        break
+                    except OutOfBlocks:
+                        # atomic: the failed prepare touched neither pool
+                        # nor state
+                        self._preempt_youngest()
+                        live = [i for i, slot in enumerate(self.slots)
+                                if slot is not None]
+                        dec.restart()
+                if canary and self.engine.last_canary_logits is not None:
+                    self._record_canary(live)
+                with phase(tr, "sync", s):
+                    if spec_out is not None:
+                        xs_h, a_h = spec_out
+                        # beam tracking sees the stepwise token
+                        toks_h = xs_h[:, 0]
+                        done_h, lp_h, ng_h = jax.device_get(
+                            (self.state.done, self.state.logprob_sum,
+                             self.state.n_gen))
+                    else:
+                        xs_h = a_h = None
+                        toks_h, done_h, lp_h, ng_h = jax.device_get(
+                            (toks, self.state.done, self.state.logprob_sum,
+                             self.state.n_gen))
+                dec.set(batch=len(live))
+            with phase(tr, "post", s):
+                self._post_step(live, toks_h, done_h, lp_h, ng_h, xs_h, a_h)
+                wall = self._clock() - t_wall
+                if prof is not None:
+                    prof.end_step(wall)
+                self.metrics.wall_s += wall
+                self.metrics.record(StepRecord(
+                    step=s, occupancy=len(live), admitted=admitted,
+                    prefill_tokens=prefill_tokens, wall_s=wall,
+                    decode_tokens=(int(a_h[np.asarray(live, np.int64)].sum())
+                                   if a_h is not None else None)))
+            step_phase.set(occupancy=len(live))
+        self.step_count += 1
+        return True
+
+    def _post_step(self, live, toks_h, done_h, lp_h, ng_h, xs_h, a_h):
+        """A step's host bookkeeping once its tokens are on the host: the
+        token events, each row's tokens, releases and prefix-cache
+        re-inserts, beam boundaries and the gauges."""
+        tr = self.tracer
+        s = self.step_count
         if tr is not None:
-            t_step = tr.now()
-        if prof is not None:
-            prof.begin_step()
-        admitted, prefill_tokens = self._admit()
-        if tr is not None:
-            tr.span("admit", t_step, step=self.step_count,
-                    admitted=admitted, prefill_tokens=prefill_tokens)
-        live = [i for i, s in enumerate(self.slots) if s is not None]
-        if not live:
-            return False
-        if self._draft is not None:
-            self._sync_draft_admissions(live)
-        for i in live:
-            if self.slots[i].first_decode_step < 0:
-                self.slots[i].first_decode_step = self.step_count
-        canary = (prof is not None and self.paged and prof.want_canary())
-        # speculative rounds need greedy sampling (acceptance compares
-        # argmaxes) and skip canary steps, whose exact-path replica is
-        # defined over the single-token decode step
-        spec_round = (self.spec is not None and sc.greedy and self.paged
-                      and not canary)
-        spec_out = None
-        while True:
-            try:
-                if tr is not None:
-                    t_dec = tr.now()
-                if spec_round:
-                    spec_out = self._spec_step(rng, sc)
-                if spec_out is None:
-                    self.state, toks = self.engine.step(
-                        self.state, rng, sc, stop_ids=self.stop_ids,
-                        row_stops=self._row_stops(), canary=canary)
-                break
-            except OutOfBlocks:
-                # atomic: the failed prepare touched neither pool nor state
-                self._preempt_youngest()
-                live = [i for i, s in enumerate(self.slots) if s is not None]
-        if canary and self.engine.last_canary_logits is not None:
-            self._record_canary(live)
-        if spec_out is not None:
-            xs_h, a_h = spec_out
-            toks_h = xs_h[:, 0]  # beam tracking sees the stepwise token
-            done_h, lp_h, ng_h = jax.device_get(
-                (self.state.done, self.state.logprob_sum,
-                 self.state.n_gen))
-        else:
-            a_h = None
-            toks_h, done_h, lp_h, ng_h = jax.device_get(
-                (toks, self.state.done, self.state.logprob_sum,
-                 self.state.n_gen))
-        if tr is not None:
-            # closes after the device_get sync above, so the span is the
-            # host-visible latency of this decode step
-            tr.span("decode", t_dec, step=self.step_count, batch=len(live))
             seen: set = set()
             for i in live:
                 rid = self.slots[i].req.req_id
-                if (self.slots[i].first_decode_step == self.step_count
+                if (self.slots[i].first_decode_step == s
                         and rid not in self._ft_emitted):
                     self._ft_emitted.add(rid)
-                    tr.event("first_token", rid, step=self.step_count)
+                    tr.event("first_token", rid, step=s)
                 # every live non-beam row sampled a token this step (stop
                 # tokens included); beam lanes are tracked in _beam_track
                 if self.slots[i].req.search is None and rid not in seen:
                     seen.add(rid)
-                    tr.event("token", rid, step=self.step_count)
+                    tr.event("token", rid, step=s)
         released = []
         over_budget = []
         released_reqs: list[tuple] = []
@@ -2591,7 +2623,7 @@ class ContinuousScheduler:
                 # blocks still hold valid prompt KV (full prompt blocks
                 # sit below the write frontier and are never CoW'd)
                 table = np.asarray(jax.device_get(self.state.cache["table"]))
-                seen: set = set()
+                seen = set()
                 for r, req in released_reqs:
                     if req.req_id in seen:  # one insert per group, not row
                         continue
@@ -2616,8 +2648,7 @@ class ContinuousScheduler:
                         by_req.setdefault(self.slots[r].req.req_id,
                                           []).append(int(r))
                     for rid, rs in by_req.items():
-                        tr.event("freeze", rid, step=self.step_count,
-                                 rows=rs)
+                        tr.event("freeze", rid, step=s, rows=rs)
             for run in boundaries:
                 self._beam_boundary(run)
         if self.paged:
@@ -2630,36 +2661,13 @@ class ContinuousScheduler:
             tr.gauge("occupancy", len(live))
             if self.paged:
                 tr.gauge("free_blocks", self.engine.pool.free_blocks)
-                # device-memory watermark: the storage this pool physically
-                # backs vs the bytes its live blocks actually hold — the
-                # counter-track pair that shows memory pressure alongside
-                # occupancy in Perfetto
-                tr.gauge("pool_reserved_bytes",
-                         self.engine.pool.n_blocks * self._block_bytes)
+                # the bytes the pool's live blocks hold, beside occupancy
+                # on the Perfetto timeline
                 tr.gauge("kv_bytes_in_use",
                          self.engine.pool.blocks_in_use * self._block_bytes)
                 if self.cache is not None:
                     tr.gauge("cache_pinned_blocks",
                              self.cache.n_cached_blocks)
-        wall = self._clock() - t_wall
-        if prof is not None:
-            prof.end_step(wall)
-            if tr is not None:
-                # attributed device cost as counter tracks, so host spans
-                # and kernel time line up on one Perfetto timeline
-                for k, v in prof.last_step_gauges.items():
-                    tr.gauge(k, v)
-        self.metrics.wall_s += wall
-        self.metrics.record(StepRecord(
-            step=self.step_count, occupancy=len(live), admitted=admitted,
-            prefill_tokens=prefill_tokens, wall_s=wall,
-            decode_tokens=(int(a_h[np.asarray(live, np.int64)].sum())
-                           if a_h is not None else None)))
-        if tr is not None:
-            tr.span("step", t_step, step=self.step_count,
-                    occupancy=len(live))
-        self.step_count += 1
-        return True
 
     # -- drain ---------------------------------------------------------------
     def run(self, rng, sc: SamplerConfig = SamplerConfig(),

@@ -136,10 +136,8 @@ class KernelProfiler:
         self._in_step = False
         self._sample_this_step = True          # standalone phases sample
         self._step_wall = 0.0                  # sampled phase walls, this step
-        self._step_bound = 0.0
         self._step_walls: list[float] = []     # scheduler wall of sampled steps
         self._kernel_walls: list[float] = []   # phase-wall sum of sampled steps
-        self.last_step_gauges: dict[str, float] = {}
         # canary
         self._canary_samples = 0
         self._canary_rows = 0
@@ -232,7 +230,6 @@ class KernelProfiler:
         ph["sampled"] += 1
         ph["wall_s"] += wall
         self._step_wall += wall
-        self._step_bound += bound
         if wall > 0.0 and bound > 0.0:
             eff = bound / wall
             ph.setdefault("_effs", []).append(eff)
@@ -252,7 +249,6 @@ class KernelProfiler:
             self.sample_interval > 0
             and self._step_idx % self.sample_interval == 0)
         self._step_wall = 0.0
-        self._step_bound = 0.0
 
     def want_canary(self) -> bool:
         """True when the current step should re-run rows through the
@@ -262,18 +258,11 @@ class KernelProfiler:
 
     def end_step(self, wall_s: float) -> None:
         """Scheduler step end; ``wall_s`` is the scheduler's own step
-        wall (tracer-clocked).  Exposes the step's kernel-time gauges in
-        :attr:`last_step_gauges` for the tracer's counter tracks."""
+        wall (tracer-clocked)."""
         if self._sample_this_step:
             self._sampled_steps += 1
             self._step_walls.append(wall_s)
             self._kernel_walls.append(self._step_wall)
-            self.last_step_gauges = {
-                "kernel_time_s": self._step_wall,
-                "roofline_bound_s": self._step_bound,
-            }
-        else:
-            self.last_step_gauges = {}
         self._step_idx += 1
         self._in_step = False
         self._sample_this_step = True  # standalone phases keep sampling

@@ -9,10 +9,22 @@ observable without touching the hot path when disabled:
   lifecycle events** (``enqueue -> admit[cache hit / lease width] ->
   first_token -> per-step token -> freeze/resume -> beam_boundary ->
   preempt/readmit -> release``), **phase spans** inside the scheduler and
-  engine (``step ⊃ {admit ⊃ prefill, decode ⊃ plan, prm}`` — admission
-  planning, prefill calls, the decode step, the paged CoW/alloc host
-  planning, PRM score callbacks) and **per-step gauges** (free pool
-  blocks, prefix-cache pinned blocks, slot occupancy).
+  engine and **per-step gauges** (free pool blocks, KV bytes in use,
+  prefix-cache pinned blocks, slot occupancy).  One scheduler step is the
+  span tree
+
+      step ⊃ { admit ⊃ prefill*,
+               decode ⊃ { plan, dispatch, sync },
+               post ⊃ prm* }
+
+  ``admit`` is admission (prefix-cache leases, prefills); ``plan`` the
+  paged CoW/alloc host planning; ``dispatch`` the call into the jitted
+  step; ``sync`` the ``jax.device_get`` of the step's tokens; ``post``
+  the per-row bookkeeping, releases, prefix-cache re-inserts, beam
+  boundaries (``prm`` score callbacks) and gauges.  Speculative rounds
+  add ``spec_verify`` (with its own ``plan``) inside ``decode``, and a
+  ``gc`` span marks every garbage collection while the tracer is bound
+  to a scheduler, wherever it falls.
 * :meth:`Tracer.request_latency` — derives one
   :class:`RequestLatency` record per request from the event stream:
   queue wait (enqueue -> first admit), TTFT (enqueue -> first token),
@@ -33,18 +45,28 @@ observable without touching the hot path when disabled:
 sub-microsecond).  Tests inject a deterministic counter so latency
 derivations are exact; the scheduler uses the same clock for its per-step
 ``wall_s``, so ``step_time_*`` percentiles are deterministic under an
-injected clock too.  Spans measure *host-side* time: the decode span
-closes after the scheduler's device sync (``jax.device_get`` of the
-step's tokens), so it reflects real step latency, while the prefill span
-closes at dispatch return (jax is async; the next sync absorbs the
-device tail).
+injected clock too (``gc`` spans are then off: a collection falls where
+the allocator puts it, not where the program does).  Spans measure
+*host-side* time: the decode span closes after its ``sync``, so it
+reflects real step latency, while the prefill span closes at dispatch
+return (jax is async; the next sync absorbs the device tail).
+
+**The profiler's clock.**  :meth:`Tracer.phase` also holds a
+``jax.profiler.TraceAnnotation`` named ``repro.<phase>`` while the phase
+is open (``repro.gc`` for collections), so with ``jax.profiler`` tracing
+the same phases appear as host spans beside the device's operations and
+a gap in the device's work can be read as what the host was doing.  With
+the profiler off an annotation costs well under a microsecond.
 
 **Zero overhead when disabled.**  The scheduler and engine hold
 ``tracer=None`` by default and guard every touchpoint with ``if tracer
-is not None`` — no events, no allocations, bit-identical outputs.
+is not None``, or open phases through :func:`phase`, which hands out the
+shared no-op :data:`NO_PHASE` for ``None`` — no events, no allocations,
+no annotations, bit-identical outputs.
 """
 from __future__ import annotations
 
+import gc
 import json
 import sys
 import time
@@ -52,6 +74,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 # Event kinds the scheduler/engine emit, in (one possible) lifecycle
 # order.  ``token`` is per request per decode step; the rest are
@@ -70,7 +93,8 @@ EVENT_KINDS = (
     "release",        # rows freed; args: rows, reason
 )
 
-SPAN_NAMES = ("step", "admit", "prefill", "decode", "plan", "prm")
+SPAN_NAMES = ("step", "admit", "prefill", "decode", "plan", "dispatch",
+              "sync", "post", "prm", "spec_verify", "gc")
 
 
 @dataclass
@@ -89,8 +113,7 @@ class Event:
 @dataclass
 class Span:
     """One completed phase span ``[t0, t1]`` on the scheduler's phase
-    timeline (spans nest: ``step`` contains ``admit``/``decode``/``prm``,
-    ``admit`` contains ``prefill``, ``decode`` contains ``plan``)."""
+    timeline (spans nest as the module docstring's tree shows)."""
 
     name: str
     t0: float
@@ -137,12 +160,93 @@ def percentile(xs, q: float) -> float:
     return float(np.percentile(np.asarray(xs, np.float64), q))
 
 
+class Phase:
+    """An open phase of a :class:`Tracer`: a context manager that holds
+    the ``repro.<name>`` profiler annotation while open and records the
+    :class:`Span` on a normal exit (an exception records nothing).  A
+    phase given no step takes the step of the phase it opens inside.
+    :meth:`set` adds span args known only inside the phase."""
+
+    __slots__ = ("tracer", "name", "step", "args", "t0", "_note", "_live")
+
+    def __init__(self, tracer: "Tracer", name: str, step: int, args: dict):
+        self.tracer, self.name, self.step, self.args = tracer, name, step, args
+        self._live = True
+
+    def __enter__(self) -> "Phase":
+        stack = self.tracer._open
+        if self.step < 0 and stack:
+            self.step = stack[-1].step
+        stack.append(self)
+        self._begin()
+        return self
+
+    def _begin(self) -> None:
+        self._note = TraceAnnotation(f"repro.{self.name}")
+        self._note.__enter__()
+        self.t0 = self.tracer.now()
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.tracer._open.pop()
+        if exc_type is None and self._live:
+            self.tracer.span(self.name, self.t0, step=self.step, **self.args)
+        self._note.__exit__(exc_type, exc, tb)
+        return False
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+
+    def restart(self) -> None:
+        """Start the phase afresh now: what ran so far is not its time."""
+        self._note.__exit__(None, None, None)
+        self._begin()
+
+    def cancel(self) -> None:
+        """Record no span when the phase closes."""
+        self._live = False
+
+
+class _NoPhase:
+    """The phase of no tracer: every method is a no-op."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoPhase":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        return False
+
+    def set(self, **args) -> None:
+        pass
+
+    def restart(self) -> None:
+        pass
+
+    def cancel(self) -> None:
+        pass
+
+
+NO_PHASE = _NoPhase()
+
+
+def phase(tracer: Optional["Tracer"], name: str, step: int = -1, **args):
+    """``tracer.phase(name, step, **args)``, or :data:`NO_PHASE` for no
+    tracer."""
+    if tracer is None:
+        return NO_PHASE
+    return tracer.phase(name, step, **args)
+
+
 class Tracer:
     """Append-only recorder of events, phase spans and gauges.
 
     ``clock`` is any zero-arg callable returning monotonically
     non-decreasing floats (seconds).  All recorded times are relative to
     the clock's value at construction, so traces start at t=0.
+    Garbage collections are recorded (:meth:`watch_gc`) on the wall
+    clock only: under an injected clock they would make a deterministic
+    trace depend on the allocator.
     """
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter):
@@ -152,6 +256,8 @@ class Tracer:
         self.spans: list[Span] = []
         self.gauges: list[Gauge] = []
         self._by_req: dict[int, list[Event]] = {}
+        self._open: list[Phase] = []        # phases open now, innermost last
+        self._gc_open: Optional[Phase] = None
 
     # -- recording -----------------------------------------------------------
     def now(self) -> float:
@@ -177,6 +283,33 @@ class Tracer:
     def gauge(self, name: str, value) -> None:
         self.gauges.append(Gauge(name=name, t=self.now(),
                                  value=float(value)))
+
+    def phase(self, name: str, step: int = -1, **args) -> Phase:
+        """``with tracer.phase("sync", step):`` records the block as a
+        ``sync`` span and holds a ``repro.sync`` profiler annotation while
+        it runs."""
+        return Phase(self, name, step, args)
+
+    # -- garbage collections -------------------------------------------------
+    def watch_gc(self) -> None:
+        """Record each garbage collection as a ``gc`` span (args: the
+        generation and the objects collected), under a ``repro.gc``
+        annotation, until :meth:`unwatch_gc`.  A no-op under an injected
+        clock."""
+        if self.clock is time.perf_counter and self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def unwatch_gc(self) -> None:
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, stage: str, info: dict) -> None:
+        if stage == "start":
+            self._gc_open = self.phase("gc").__enter__()
+        elif self._gc_open is not None:
+            ph, self._gc_open = self._gc_open, None
+            ph.set(generation=info["generation"], collected=info["collected"])
+            ph.__exit__(None, None, None)
 
     # -- derivation ----------------------------------------------------------
     def request_events(self, req_id: int) -> list[Event]:

@@ -3,6 +3,7 @@ clock, scheduler event-stream integration (admit/preempt/readmit/release
 ordering, beam boundary/freeze/resume), null-tracer parity (zero overhead
 when disabled), Chrome-trace export validity, and the summary() latency
 keys' robustness on empty drains."""
+import gc
 import itertools
 import json
 
@@ -13,6 +14,7 @@ import pytest
 
 from repro.serving.engine import (BeamSpec, ContinuousScheduler, DecodeEngine,
                                   Request)
+from repro.serving import telemetry
 from repro.serving.sampler import SamplerConfig
 from repro.serving.telemetry import (Tracer, main, percentile,
                                      validate_chrome_trace)
@@ -351,3 +353,153 @@ def test_step_once_wall_time_is_per_step(engine, tok):
     assert recs and all(r.wall_s > 0 for r in recs)
     assert sched.metrics.wall_s == pytest.approx(
         sum(r.wall_s for r in recs))
+
+
+# ---------------------------------------------------------------------------
+# Phases on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+class _Notes:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs what opens."""
+
+    def __init__(self):
+        self.opened = []
+
+    def __call__(self, name):
+        log = self.opened
+
+        class _Note:
+            def __enter__(self):
+                log.append(name)
+
+            def __exit__(self, *exc):
+                return False
+
+        return _Note()
+
+
+def test_phase_records_nested_spans_under_annotations(monkeypatch):
+    notes = _Notes()
+    monkeypatch.setattr(telemetry, "TraceAnnotation", notes)
+    c = ManualClock()
+    tr = Tracer(clock=c)
+    c.t = 1.0
+    with tr.phase("step", 0) as step:
+        c.t = 2.0
+        with tr.phase("sync", 0, rows=2):
+            c.t = 3.0
+        with tr.phase("post", 0) as post:
+            post.set(released=1)
+            c.t = 4.0
+        step.set(occupancy=2)
+        c.t = 5.0
+    assert [(s.name, s.t0, s.t1, s.step, s.args) for s in tr.spans] == [
+        ("sync", 2.0, 3.0, 0, {"rows": 2}),
+        ("post", 3.0, 4.0, 0, {"released": 1}),
+        ("step", 1.0, 5.0, 0, {"occupancy": 2})]
+    assert notes.opened == ["repro.step", "repro.sync", "repro.post"]
+    # an exception or a cancel records no span; a restart moves the start
+    with pytest.raises(RuntimeError):
+        with tr.phase("admit", 1):
+            raise RuntimeError("boom")
+    with tr.phase("admit", 1) as ph:
+        ph.cancel()
+    with tr.phase("decode", 1) as ph:
+        c.t = 6.0
+        ph.restart()
+        c.t = 7.0
+    assert [(s.name, s.t0, s.t1) for s in tr.spans[3:]] == [
+        ("decode", 6.0, 7.0)]
+    assert validate_chrome_trace(tr.to_chrome_trace()) == []
+    assert telemetry.phase(None, "step", 0) is telemetry.NO_PHASE
+
+
+def _paged_run(trained_tiny, tiny_cfg, tok, tracer):
+    eng = DecodeEngine(trained_tiny, tiny_cfg, max_len=64,
+                       eos_id=tok.eos_id, pad_id=tok.pad_id, paged=True,
+                       block_size=8, n_blocks=40)
+    sched = ContinuousScheduler(eng, n_slots=3, prompt_len=16,
+                                stop_ids=NO_STOP, tracer=tracer)
+    for i, (text, max_new) in enumerate(_REQS):
+        sched.submit(_req(tok, i, text, max_new, n_samples=1 + i % 2))
+    return sched.run(jax.random.key(0), SamplerConfig(temperature=0.8,
+                                                      top_p=0.9))
+
+
+def test_step_span_holds_its_phases(trained_tiny, tiny_cfg, tok):
+    """step ⊃ {admit, decode ⊃ {plan, dispatch, sync}, post}, in that
+    order, on every step."""
+    tr = Tracer(clock=_counting_clock())
+    _paged_run(trained_tiny, tiny_cfg, tok, tr)
+    by = {}
+    for sp in tr.spans:
+        by.setdefault(sp.name, []).append(sp)
+    steps = {sp.step: sp for sp in by["step"]}
+    assert len(steps) == len(by["step"]) > 3
+
+    def inside(inner, outer):
+        return outer.t0 <= inner.t0 <= inner.t1 <= outer.t1
+
+    for name in ("admit", "decode", "sync", "post"):
+        of_step = {sp.step: sp for sp in by[name] if sp.step in steps}
+        assert set(of_step) == set(steps), name
+        for s, sp in of_step.items():
+            assert inside(sp, steps[s]), (name, s)
+    for s, step in steps.items():
+        dec = next(sp for sp in by["decode"] if sp.step == s)
+        sync = next(sp for sp in by["sync"] if sp.step == s)
+        post = next(sp for sp in by["post"] if sp.step == s)
+        admit = next(sp for sp in by["admit"] if sp.step == s)
+        assert inside(sync, dec) and admit.t1 <= dec.t0 <= dec.t1 <= post.t0
+        for name in ("plan", "dispatch"):
+            assert sum(inside(sp, dec) for sp in by[name]) == 1, (name, s)
+    for sp in by["prefill"]:
+        assert any(inside(sp, a) for a in by["admit"])
+    assert validate_chrome_trace(tr.to_chrome_trace()) == []
+
+
+def test_null_tracer_opens_no_phase(trained_tiny, tiny_cfg, tok, monkeypatch):
+    """tracer=None: the same tokens as a traced run, and no span, no
+    annotation and no gc hook is made."""
+    notes = _Notes()
+    monkeypatch.setattr(telemetry, "TraceAnnotation", notes)
+    hooks = list(gc.callbacks)
+    off = _paged_run(trained_tiny, tiny_cfg, tok, None)
+    assert notes.opened == [] and gc.callbacks == hooks
+    tr = Tracer(clock=_counting_clock())
+    on = _paged_run(trained_tiny, tiny_cfg, tok, tr)
+    assert on == off
+    # every phase opened its annotation; the drain's last, idle step
+    # cancelled its step span
+    assert tr.spans and len(notes.opened) == len(tr.spans) + 1
+
+
+def test_gc_spans_while_the_tracer_is_bound(engine):
+    tr = Tracer()
+    sched = ContinuousScheduler(engine, n_slots=2, prompt_len=16,
+                                stop_ids=NO_STOP, tracer=tr)
+    try:
+        gc.collect()
+        spans = [sp for sp in tr.spans if sp.name == "gc"]
+        assert spans and spans[-1].args["generation"] == 2
+        assert spans[-1].args["collected"] >= 0
+        assert spans[-1].t1 >= spans[-1].t0
+        assert validate_chrome_trace(tr.to_chrome_trace()) == []
+        # a new scheduler takes the engine's tracer slot: the old tracer
+        # stops recording collections
+        ContinuousScheduler(engine, n_slots=2, prompt_len=16,
+                            stop_ids=NO_STOP)
+        n = len(tr.spans)
+        gc.collect()
+        assert len(tr.spans) == n
+        assert tr._on_gc not in gc.callbacks
+        # an injected clock records no collections
+        tr2 = Tracer(clock=_counting_clock())
+        sched = ContinuousScheduler(engine, n_slots=2, prompt_len=16,
+                                    stop_ids=NO_STOP, tracer=tr2)
+        gc.collect()
+        assert not [sp for sp in tr2.spans if sp.name == "gc"]
+    finally:
+        sched.tracer = None
+    assert tr._on_gc not in gc.callbacks
