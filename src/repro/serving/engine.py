@@ -97,7 +97,8 @@ from repro.models import api
 from repro.serving.kv_pool import KVPool, OutOfBlocks, blocks_for
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.profiling import NULL_PROFILE_METRICS
-from repro.serving.sampler import SamplerConfig, logprobs_of, sample
+from repro.serving.sampler import (SamplerConfig, candidates, logprobs_of,
+                                   sample)
 from repro.serving.telemetry import RequestLatency, Tracer, percentile, phase
 
 
@@ -2557,7 +2558,8 @@ class ContinuousScheduler:
                              self.state.n_gen))
                 dec.set(batch=len(live))
             with phase(tr, "post", s):
-                self._post_step(live, toks_h, done_h, lp_h, ng_h, xs_h, a_h)
+                self._post_step(live, toks_h, done_h, lp_h, ng_h, xs_h, a_h,
+                                sc)
                 wall = self._clock() - t_wall
                 if prof is not None:
                     prof.end_step(wall)
@@ -2571,7 +2573,7 @@ class ContinuousScheduler:
         self.step_count += 1
         return True
 
-    def _post_step(self, live, toks_h, done_h, lp_h, ng_h, xs_h, a_h):
+    def _post_step(self, live, toks_h, done_h, lp_h, ng_h, xs_h, a_h, sc):
         """A step's host bookkeeping once its tokens are on the host: the
         token events, each row's tokens, releases and prefix-cache
         re-inserts, beam boundaries and the gauges."""
@@ -2659,6 +2661,9 @@ class ContinuousScheduler:
                 self.engine.pool.peak_in_use * self._block_bytes)
         if tr is not None:
             tr.gauge("occupancy", len(live))
+            # the sampler's path: k candidates a row, or 0 for the whole
+            # vocabulary
+            tr.gauge("sampler_candidates", candidates(sc))
             if self.paged:
                 tr.gauge("free_blocks", self.engine.pool.free_blocks)
                 # the bytes the pool's live blocks hold, beside occupancy

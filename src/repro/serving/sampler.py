@@ -28,12 +28,6 @@ class SamplerConfig:
     greedy: bool = False
 
 
-def _mask_top_k(logits, k):
-    vals, _ = jax.lax.top_k(logits, k)
-    thresh = vals[..., -1:]
-    return jnp.where(logits >= thresh, logits, -jnp.inf)
-
-
 def _mask_top_p(logits, p):
     # argsort is stable, so among tied logits lower token ids sort first
     order = jnp.argsort(-logits, axis=-1)
@@ -48,13 +42,36 @@ def _mask_top_p(logits, p):
     return jnp.where(ranks <= cutoff_idx, logits, -jnp.inf)
 
 
+def _sample_candidates(x, rng, k, p):
+    """Gumbel-max over the k best of ``x`` (B, V), nucleus ``p`` taken over
+    those k.  ``lax.top_k`` is the one full-width pass: it ranks ties by
+    the lower id and keeps exactly k.  The noise is the full (B, V) draw
+    ``jax.random.categorical`` makes, read at the candidates' ids, so a
+    row whose k cover the vocabulary samples what categorical does."""
+    vals, idx = jax.lax.top_k(x, min(k, x.shape[-1]))
+    if p < 1.0:
+        probs = jax.nn.softmax(vals, axis=-1)
+        # the mass above each candidate; the first is always kept
+        vals = jnp.where(jnp.cumsum(probs, axis=-1) - probs < p, vals,
+                         -jnp.inf)
+    g = jax.random.gumbel(rng, x.shape, x.dtype)
+    j = jnp.argmax(vals + jnp.take_along_axis(g, idx, axis=-1), axis=-1)
+    return jnp.take_along_axis(idx, j[..., None], axis=-1)[..., 0]
+
+
+def candidates(sc: SamplerConfig) -> int:
+    """Tokens per row that :func:`sample` ranks and samples among: ``top_k``
+    on the candidate path, 0 on the full-vocabulary paths."""
+    return 0 if sc.greedy else sc.top_k
+
+
 def sample(logits: jnp.ndarray, rng, sc: SamplerConfig) -> jnp.ndarray:
     """logits: (B, V) f32 -> tokens (B,) int32."""
     if sc.greedy:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     x = logits / jnp.maximum(sc.temperature, 1e-6)
     if sc.top_k:
-        x = _mask_top_k(x, sc.top_k)
+        return _sample_candidates(x, rng, sc.top_k, sc.top_p)
     if sc.top_p < 1.0:
         x = _mask_top_p(x, sc.top_p)
     return jax.random.categorical(rng, x, axis=-1).astype(jnp.int32)

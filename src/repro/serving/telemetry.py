@@ -10,8 +10,8 @@ observable without touching the hot path when disabled:
   first_token -> per-step token -> freeze/resume -> beam_boundary ->
   preempt/readmit -> release``), **phase spans** inside the scheduler and
   engine and **per-step gauges** (free pool blocks, KV bytes in use,
-  prefix-cache pinned blocks, slot occupancy).  One scheduler step is the
-  span tree
+  prefix-cache pinned blocks, slot occupancy, the sampler's candidates a
+  row).  One scheduler step is the span tree
 
       step ⊃ { admit ⊃ prefill*,
                decode ⊃ { plan, dispatch, sync },

@@ -12,7 +12,7 @@ from repro.data import tasks as T
 from repro.models import api
 from repro.serving.engine import (ContinuousScheduler, DecodeEngine,
                                   GenState, Request)
-from repro.serving.sampler import SamplerConfig, sample
+from repro.serving.sampler import SamplerConfig, _mask_top_p, sample
 
 
 @pytest.fixture(scope="module")
@@ -209,6 +209,76 @@ def test_sampler_top_k_top_p():
         assert int(t[0]) == 0
     t = sample(logits, jax.random.key(9), SamplerConfig(top_p=0.5))
     assert int(t[0]) == 0  # nucleus of 0.5 keeps only the argmax here
+
+
+def _full_vocab_sample(logits, rng, sc):
+    """The full-vocabulary masks: every logit at or above the k-th kept,
+    the nucleus cut by sorted rank over the whole vocabulary, then
+    ``jax.random.categorical``."""
+    x = logits / sc.temperature
+    thresh = jax.lax.top_k(x, sc.top_k)[0][..., -1:]
+    x = _mask_top_p(jnp.where(x >= thresh, x, -jnp.inf), sc.top_p)
+    return jax.random.categorical(rng, x, axis=-1)
+
+
+@pytest.mark.parametrize("k", [1, 20])
+@pytest.mark.parametrize("temperature", [0.7, 1.3])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_sampler_candidates_match_full_vocab_masks(seed, temperature, k):
+    """Sampling within the top-k candidates picks the token the
+    full-vocabulary masks pick, except where the nucleus mass meets p
+    within f32 rounding."""
+    B, V, p = 64, 4096, 0.8
+    kl, kr = jax.random.split(jax.random.key(seed))
+    logits = 3.0 * jax.random.normal(kl, (B, V), jnp.float32)
+    sc = SamplerConfig(temperature=temperature, top_k=k, top_p=p)
+    got = np.asarray(sample(logits, kr, sc))
+    want = np.asarray(_full_vocab_sample(logits, kr, sc))
+    differ = got != want
+    assert differ.mean() <= 0.01
+    vals = jax.lax.top_k(logits / temperature, k)[0]
+    cum = np.asarray(jnp.cumsum(jax.nn.softmax(vals, axis=-1), axis=-1))
+    assert np.all(np.abs(cum[differ] - p).min(axis=-1) <= 1e-5)
+
+
+def test_sampler_candidates_tie_keeps_exactly_k():
+    """A four-way tie at the k-th value keeps exactly k tokens, the tied
+    ones by lowest id (``lax.top_k``'s order)."""
+    logits = jnp.full((400, 10), -5.0).at[:, 5].set(1.2).at[:, 0].set(1.1)
+    logits = logits.at[:, jnp.array([7, 2, 9, 4])].set(1.0)
+    toks = sample(logits, jax.random.key(0),
+                  SamplerConfig(top_k=3, top_p=1.0))
+    assert set(np.asarray(toks).tolist()) == {5, 0, 2}
+
+
+@pytest.mark.parametrize("temperature", [0.7, 1.0])
+def test_sampler_candidates_over_whole_vocab_is_categorical(temperature):
+    """With k the whole vocabulary and no nucleus the candidate path
+    returns ``jax.random.categorical``'s tokens for the same key: the
+    noise is the same (B, V) draw."""
+    B, V = 64, 1000
+    kl, kr = jax.random.split(jax.random.key(5))
+    logits = 2.0 * jax.random.normal(kl, (B, V), jnp.float32)
+    got = sample(logits, kr, SamplerConfig(temperature=temperature,
+                                           top_k=V, top_p=1.0))
+    want = jax.random.categorical(kr, logits / temperature, axis=-1)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_sampler_top_k_lowers_without_full_vocab_sort():
+    """With a top-k the sampler ranks the vocabulary once, by
+    ``lax.top_k``, and sorts nothing at full width; without one the
+    nucleus still sorts the whole vocabulary."""
+    logits = jax.ShapeDtypeStruct((64, 151936), jnp.float32)
+    lower = jax.jit(sample, static_argnums=2).lower
+
+    hlo = lower(logits, jax.random.key(0),
+                SamplerConfig(0.7, top_k=20, top_p=0.8)).as_text()
+    assert "chlo.top_k" in hlo
+    assert "stablehlo.sort" not in hlo
+    hlo = lower(logits, jax.random.key(0),
+                SamplerConfig(0.7, top_p=0.8)).as_text()
+    assert "stablehlo.sort" in hlo
 
 
 def test_sampler_top_p_ties_respect_target_mass():

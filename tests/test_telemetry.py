@@ -185,12 +185,12 @@ _REQS = [("Q:2+7=?A:", 7), ("Q:1+1=?A:", 2), ("Q:9+9=?A:", 5),
          ("Q:4+5=?A:", 3)]
 
 
-def _run(engine, tok, tracer):
+def _run(engine, tok, tracer, sc=GREEDY):
     sched = ContinuousScheduler(engine, n_slots=2, prompt_len=16,
                                 stop_ids=NO_STOP, tracer=tracer)
     for i, (text, max_new) in enumerate(_REQS):
         sched.submit(_req(tok, i, text, max_new))
-    res = sched.run(jax.random.key(0), GREEDY)
+    res = sched.run(jax.random.key(0), sc)
     return res, sched
 
 
@@ -238,6 +238,22 @@ def test_traced_run_deterministic_under_injected_clock(engine, tok):
     assert ([a.request_latency(i) for i in range(len(_REQS))]
             == [b.request_latency(i) for i in range(len(_REQS))])
     assert a.to_chrome_trace() == b.to_chrome_trace()
+
+
+@pytest.mark.parametrize("sc,want", [
+    (SamplerConfig(temperature=0.7, top_k=5, top_p=0.8), 5),
+    (SamplerConfig(temperature=0.7, top_p=0.8), 0),
+    (GREEDY, 0),
+])
+def test_sampler_candidates_gauge_per_step(engine, tok, sc, want):
+    """Every decode step records the sampler's path: k candidates a row
+    with a top-k, 0 for the full-vocabulary and greedy paths."""
+    tr = Tracer(clock=_counting_clock())
+    _, sched = _run(engine, tok, tr, sc)
+    got = [g.value for g in tr.gauges if g.name == "sampler_candidates"]
+    assert len(got) == sched.metrics.summary()["steps"]
+    assert set(got) == {want}
+    assert validate_chrome_trace(tr.to_chrome_trace()) == []
 
 
 def test_lifecycle_event_ordering(engine, tok):
