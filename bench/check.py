@@ -2,8 +2,9 @@
 
 Once the window has closed, a sample of the requests it finished is drawn
 from the seed, the longest among them.  For every served token of every
-sample of those requests the plain float32 reference (``bench/reference``)
-is run over the prompt and the served tokens before it, and the token is
+sample of those requests the plain float32 reference of the model's
+family (``bench/families``; the dense one's is ``bench/reference``) is
+run over the prompt and the served tokens before it, and the token is
 judged by the *gap*: how far its perturbed score lies below the best
 perturbed score the reference allows.
 
@@ -16,7 +17,7 @@ logits: a sound program's token is the reference's choice, or loses to
 it only by rounding.  Gaps are in logit units (scores times T).
 
 The control puts the reference itself in the program's place in the next
-precision below the configuration's bf16 (fp8 matrices, see
+precision below the configuration's bf16 (the family's fp8 control, see
 ``reference.model``): at each position it reads the gap of the token the
 fp8 forward puts first.
 """
@@ -29,8 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import generator as gen_mod
-from bench.reference import model as ref
 
+HIGHEST = jax.lax.Precision.HIGHEST
 CHUNK = 32          # positions per reference logits block
 # the longest finished request and others drawn from the seed: enough that
 # the rows compared lie in both halves of the batch
@@ -171,12 +172,12 @@ def _request_gaps(head, ctrl_head, H, Hc, keys, rows, toks, *, n_slots, sp,
         noise = jax.lax.map(
             lambda k: jax.random.gumbel(k, (n_slots, V), jnp.float32)[rows], ks)
         noise = jnp.swapaxes(noise, 0, 1)                    # (n, C, V)
-        logits = jnp.einsum("ncd,vd->ncv", hs, head, precision=ref.HIGHEST)
+        logits = jnp.einsum("ncd,vd->ncv", hs, head, precision=HIGHEST)
         served = _gap(logits, noise, tk, sp)
         if not control:
             return served, jnp.zeros_like(served)
         hc = jax.lax.dynamic_slice_in_dim(Hc, c * CHUNK, CHUNK, axis=1)
-        lc = jnp.einsum("ncd,vd->ncv", hc, ctrl_head, precision=ref.HIGHEST)
+        lc = jnp.einsum("ncd,vd->ncv", hc, ctrl_head, precision=HIGHEST)
         return served, _gap(logits, noise, _choice(lc, noise, sp), sp)
 
     g, gc = jax.lax.map(chunk, jnp.arange(P // CHUNK))
@@ -184,7 +185,7 @@ def _request_gaps(head, ctrl_head, H, Hc, keys, rows, toks, *, n_slots, sp,
             jnp.moveaxis(gc, 0, 1).reshape(n, P))
 
 
-def hidden_rows(params, m, prompt, tokens, max_len, P, weights):
+def hidden_rows(params, m, prompt, tokens, max_len, P, weights, family):
     """Reference hidden states at the positions that produced ``tokens``:
     position plen-1+t gives the logits of token t."""
     plen = len(prompt)
@@ -192,10 +193,11 @@ def hidden_rows(params, m, prompt, tokens, max_len, P, weights):
     full = np.concatenate([prompt, np.asarray(tokens[:-1], np.int32)])
     seq[:len(full)] = full
     pos = np.clip(plen - 1 + np.arange(P), 0, max_len - 1).astype(np.int32)
-    return jnp.take(ref.hidden(params, seq, m, weights), pos, axis=0)
+    return jnp.take(family.hidden(params, seq, m, weights), pos, axis=0)
 
 
-def request_gaps(params, heads, config, mix, req, keys, control: bool):
+def request_gaps(params, heads, config, mix, req, keys, control: bool,
+                 family):
     m = config["model"]
     s = config["sampler"]
     sp = (float(s["temperature"]), int(s["top_k"]), float(s["top_p"]),
@@ -203,9 +205,9 @@ def request_gaps(params, heads, config, mix, req, keys, control: bool):
     P = -(-mix["output_tokens"]["max"] // CHUNK) * CHUNK
     n = len(req["tokens"])
     H = jnp.stack([hidden_rows(params, m, req["prompt"], t, mix["max_len"], P,
-                               "f32") for t in req["tokens"]])
+                               "f32", family) for t in req["tokens"]])
     Hc = (jnp.stack([hidden_rows(params, m, req["prompt"], t, mix["max_len"],
-                                 P, "fp8") for t in req["tokens"]])
+                                 P, "fp8", family) for t in req["tokens"]])
           if control else H)
     if len(set(req["first_step"])) != 1:
         raise ValueError("samples of one request decoded at different steps")
@@ -225,15 +227,17 @@ def request_gaps(params, heads, config, mix, req, keys, control: bool):
     return g[valid], gc[valid]
 
 
-def run_check(params, config, mix, picked, keys, n_failed: int,
+def run_check(params, config, mix, picked, keys, n_failed: int, family,
               control: bool = False) -> dict:
-    """Compare the picked requests with the reference; with ``control``
-    also read the fp8 control's gaps on the same tokens."""
-    heads = (ref.head(params, "f32"),
-             ref.head(params, "fp8") if control else None)
+    """Compare the picked requests with the reference of the model's
+    ``family``; with ``control`` also read the fp8 control's gaps on the
+    same tokens."""
+    heads = (family.head(params, "f32"),
+             family.head(params, "fp8") if control else None)
     served, ctrl = [], []
     for req in picked:
-        g, gc = request_gaps(params, heads, config, mix, req, keys, control)
+        g, gc = request_gaps(params, heads, config, mix, req, keys, control,
+                             family)
         served.append(g)
         ctrl.append(gc)
     served = np.concatenate(served) if served else np.zeros((0,))

@@ -46,7 +46,7 @@ def main(argv=None) -> int:
     served, ctrl = [], []
     seeds = [args.first + 7919 * i for i in range(args.seeds)] + args.also
     for seed in seeds:
-        result, verdict = harness.run_cell(
+        result, verdict, _ = harness.run_cell(
             args.workload, seed, args.seconds, False,
             t_start=time.perf_counter(), parts=parts, engine=engine,
             control=True)

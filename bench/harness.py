@@ -2,13 +2,17 @@
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
 its configuration and traffic mix in ``bench/configs`` and
-``bench/traffic``, its loop in ``bench/loops/<kind>.py`` and each metric in
-``bench/metrics/<metric>.py``.  The system under test is the serving stack
-in ``src/repro``: ``ContinuousScheduler.submit``/``step_once`` over a paged
-``DecodeEngine`` with a ``PrefixCache``, sized by the cell.
+``bench/traffic``, its model family (weights, reference and counts) in
+``bench/families/<family>.py``, its loop in ``bench/loops/<kind>.py`` and
+each metric in ``bench/metrics/<metric>.py``.  The system under test is
+the serving stack in ``src/repro``: ``ContinuousScheduler.submit``/
+``step_once`` over a paged ``DecodeEngine`` with a ``PrefixCache``, sized
+by the cell.
 """
 from __future__ import annotations
 
+import bisect
+import dataclasses
 import functools
 import gc
 import importlib
@@ -20,6 +24,7 @@ import tempfile
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Optional
 
 import numpy as np
@@ -27,7 +32,7 @@ import numpy as np
 from bench import check as checks
 from bench import cost
 from bench import generator as gen_mod
-from bench import trace_reduce
+from bench import trace_scopes
 from bench import weights as weights_mod
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -47,8 +52,8 @@ def load_benchmark(root: Path = ROOT) -> dict:
 
 
 def find_cell(bench: dict, name: str, root: Path = ROOT) -> dict:
-    """The cell, its configuration and mix files, and the metrics it
-    reports with ``--trace 0`` and ``--trace 1``."""
+    """The cell, its configuration and mix files, its model family, and
+    the metrics it reports with ``--trace 0`` and ``--trace 1``."""
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise KeyError(f"unknown workload {name!r}; known: {sorted(cells)}")
@@ -61,6 +66,7 @@ def find_cell(bench: dict, name: str, root: Path = ROOT) -> dict:
         return name in metric.get("workloads", [name])
 
     return {"cell": cell, "config": config, "mix": mix,
+            "family": load_family(config.get("family", "dense"), root),
             "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
             "per_layer": [m for m in bench["per_layer"] if applies(m)]}
 
@@ -71,6 +77,16 @@ def load_mix(name: str, root: Path = ROOT) -> dict:
 
 def load_loop(kind: str):
     return importlib.import_module(f"bench.loops.{kind}")
+
+
+@functools.cache
+def load_family(name: str, root: Path = ROOT) -> ModuleType:
+    """``bench/families/<name>.py`` (one module per name and root)."""
+    path = root / "bench" / "families" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(f"bench_family_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reader(name: str, root: Path = ROOT):
@@ -103,14 +119,35 @@ class RunRecord:
     token_times: dict              # req_id -> times of its token events
     due: dict                      # req_id -> when the client meant to send it
     peaks: dict
+    family: ModuleType             # the model's (``bench/families``)
     events: list = field(default_factory=list)   # the tracer's events
     traced: Optional[range] = None  # steps inside the traced window
-    trace: Optional[dict] = None    # trace_reduce.reduce output
+    trace: Optional[dict] = None    # trace_scopes.reduce output
     extra: dict = field(default_factory=dict)
+    spans: list = field(default_factory=list)    # the tracer's spans
+    gauges: list = field(default_factory=list)   # its gauges, with times
+
+    @functools.cached_property
+    def counters(self) -> dict:
+        """step -> {gauge name: value} (:func:`step_counters`)."""
+        return step_counters(self.step_spans, self.gauges)
+
+
+def step_counters(step_spans: dict, gauges) -> dict:
+    """``{step: {gauge name: value}}`` of the gauges recorded inside each
+    step's ``step`` span (the last value of a name, where it has many)."""
+    spans = sorted((a, b, s) for s, (a, b) in step_spans.items())
+    starts = [a for a, _, _ in spans]
+    out: dict = {}
+    for g in gauges:
+        i = bisect.bisect_right(starts, g.t) - 1
+        if i >= 0 and g.t <= spans[i][1]:
+            out.setdefault(spans[i][2], {})[g.name] = g.value
+    return out
 
 
 def record_from(sched, tracer, *, m, n_slots, setup_s, t0, t1, steps, due,
-                prompt_len_of, peaks, shared_prefix=0, traced=None,
+                prompt_len_of, peaks, family, shared_prefix=0, traced=None,
                 trace=None) -> RunRecord:
     step_spans, decode_spans = {}, {}
     for sp in tracer.spans:
@@ -147,22 +184,40 @@ def record_from(sched, tracer, *, m, n_slots, setup_s, t0, t1, steps, due,
                      kv_tokens=kv_tokens,
                      prefills=prefills, token_times=token_times, due=due,
                      peaks=peaks, events=tracer.events, traced=traced,
-                     trace=trace)
+                     trace=trace, spans=list(tracer.spans),
+                     gauges=list(tracer.gauges), family=family)
 
 
 # -- the system under test -------------------------------------------------
 
 def model_config(config: dict):
     """The registry's configuration with this file's sizes over it; every
-    size the file states must hold in what is served."""
+    size the file states must hold in what is served.  A nested group
+    (``"moe": {...}``) is applied field by field onto the registry's
+    dataclass of that name."""
     from repro.configs.registry import get_config
 
-    cfg = get_config(config["arch"]).with_(
-        **config["model"], param_dtype=config["weight_dtype"])
-    for k, v in config["model"].items():
-        if getattr(cfg, k) != v:
-            raise ValueError(f"{config['name']}: served {k}={getattr(cfg, k)}"
-                             f" differs from the file's {v}")
+    base = get_config(config["arch"])
+    model = config["model"]
+    kw = {}
+    for k, v in model.items():
+        if isinstance(v, dict):
+            group = getattr(base, k)
+            if not dataclasses.is_dataclass(group):
+                raise ValueError(f"{config['name']}: the registry's "
+                                 f"{config['arch']} has no {k} group to "
+                                 f"apply {v} onto")
+            v = dataclasses.replace(group, **v)
+        kw[k] = v
+    cfg = base.with_(**kw, param_dtype=config["weight_dtype"])
+    for k, v in model.items():
+        pairs = ([(f"{k}.{f}", getattr(getattr(cfg, k), f), want)
+                  for f, want in v.items()] if isinstance(v, dict)
+                 else [(k, getattr(cfg, k), v)])
+        for name, served, want in pairs:
+            if served != want:
+                raise ValueError(f"{config['name']}: served {name}={served}"
+                                 f" differs from the file's {want}")
     return cfg
 
 
@@ -326,16 +381,17 @@ def memory_peak() -> Optional[int]:
 def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
              t_start: float, root: Path = ROOT, parts: Optional[dict] = None,
              peaks: Optional[dict] = None, engine=None,
-             control: bool = False) -> tuple[dict, dict]:
-    """One run of one cell.  Returns the result line's object and the
+             control: bool = False) -> tuple[dict, dict, RunRecord]:
+    """One run of one cell.  Returns the result line's object, the
     check's full verdict (with ``control``, the fp8 control's readings
-    too).  ``parts`` (from :func:`find_cell`) and ``peaks`` may be given
-    by a caller that has them, such as a test on the CPU; ``engine`` by
-    one that runs many seeds of a cell in one process."""
+    too) and what the readers read.  ``parts`` (from :func:`find_cell`)
+    and ``peaks`` may be given by a caller that has them, such as a test
+    on the CPU; ``engine`` by one that runs many seeds of a cell in one
+    process."""
     import jax
 
     parts = parts or find_cell(load_benchmark(root), name, root)
-    config, mix = parts["config"], parts["mix"]
+    config, mix, family = parts["config"], parts["mix"], parts["family"]
     gen_mod.check_mix(mix)
     dev = jax.devices()[0]
     peaks = peaks or cost.load_peaks(dev.device_kind)
@@ -343,7 +399,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     counter = compile_counter()
     counter.n = 0
 
-    params = weights_mod.make(m, seed, config["weight_dtype"])
+    params = weights_mod.from_layout(family.layout(m), seed,
+                                     config["weight_dtype"])
     sched, tracer = build(config, mix, params, engine)
     sc = sampler_config(config)
     keys = np.random.default_rng([int(seed), 7]).integers(
@@ -407,11 +464,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     if trace:
         annotation.__exit__(None, None, None)
         jax.profiler.stop_trace()
+        t_read = time.perf_counter()
         try:
-            reduced = trace_reduce.reduce(
-                trace_reduce.read(trace_reduce.find_trace(trace_dir)))
+            reduced = trace_scopes.reduce(
+                trace_scopes.read(trace_scopes.find_trace(trace_dir)))
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
+        read_s = time.perf_counter() - t_read
     mem_peak = memory_peak()
 
     rec = record_from(sched, tracer, m=m, n_slots=config["slots"],
@@ -419,12 +478,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
                       due=due, prompt_len_of=prompt_len_of, peaks=peaks,
                       shared_prefix=mix["header_tokens"],
                       traced=range(traced_from, s1) if trace else None,
-                      trace=reduced)
+                      trace=reduced, family=family)
     window_gauges = [g for g in tracer.gauges if t0 <= g.t <= t1]
     rec.extra = {"compiles_in_window": counter.n, "warm_s": warm_s,
                  "preemptions": sched.metrics.preemptions,
                  "memory_peak_bytes": mem_peak,
                  "n_blocks": config["n_blocks"],
+                 "trace_read_s": read_s if trace else None,
                  "blocks_free_min": min((g.value for g in window_gauges
                                          if g.name == "free_blocks"),
                                         default=None),
@@ -448,7 +508,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     gc.collect()
     t_check = time.perf_counter()
     verdict = checks.run_check(params, config, mix, picked, keys, n_failed,
-                               control=control)
+                               family, control=control)
     rec.extra["check_s"] = time.perf_counter() - t_check
     result = {
         "correct": verdict["correct"],
@@ -470,4 +530,4 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
                                "idle_by_span": reduced["idle_by_span"]}
                               if reduced else {})}))
     result["check"] = verdict["numbers"]
-    return result, verdict
+    return result, verdict, rec

@@ -4,11 +4,8 @@
 
 
 def read(run):
-    spans = getattr(run, "spans", None)
-    if not spans:
-        return None
-    step = {sp.step: sp.t1 - sp.t0 for sp in spans if sp.name == "step"}
-    sync = {sp.step: sp.t1 - sp.t0 for sp in spans if sp.name == "sync"}
+    step = {sp.step: sp.t1 - sp.t0 for sp in run.spans if sp.name == "step"}
+    sync = {sp.step: sp.t1 - sp.t0 for sp in run.spans if sp.name == "sync"}
     steps = [s for s in run.steps if s in step and s in sync]
     if not steps:
         return None

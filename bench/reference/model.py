@@ -2,7 +2,9 @@
 
 Independent of ``src/``: straightforward ``jax.numpy`` in float32 at
 ``Precision.HIGHEST``, one sequence at a time, no cache, no kernels, no
-batching.  It reads the weight tree of ``bench/weights.py``.
+batching.  It reads the dense family's weight tree
+(``bench/families/dense.py``), which exports it as that family's
+reference.
 
 The equations are those the served program states: pre-norm residual
 blocks, RMSNorm (``x * rsqrt(mean(x^2) + eps) * scale``), rotary embedding

@@ -49,8 +49,9 @@ def main(argv=None) -> int:
               f"{devices[0].platform} device(s)", file=sys.stderr)
         return 2
     harness.enable_compile_cache()
-    result, _ = harness.run_cell(args.workload, args.seed, args.seconds,
-                                 bool(args.trace), t_start=T_START, parts=parts)
+    result, _, _ = harness.run_cell(args.workload, args.seed, args.seconds,
+                                    bool(args.trace), t_start=T_START,
+                                    parts=parts)
     for name, n in result["check"].items():
         print(f"check {name} = {n['value']!r}  limit {n['limit']!r}",
               file=sys.stderr)

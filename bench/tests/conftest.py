@@ -21,6 +21,7 @@ def tiny_parts(**config_overrides):
     mix = json.loads((DATA / "tiny-mix.json").read_text())
     bench = harness.load_benchmark()
     return {"cell": {"name": "tiny", "chips": 1}, "config": config, "mix": mix,
+            "family": harness.load_family(config.get("family", "dense")),
             "end_to_end": bench["end_to_end"], "per_layer": bench["per_layer"]}
 
 
@@ -29,10 +30,10 @@ def run_tiny(seed: int, seconds: float = 1.5, parts=None, control=False):
     chip is the command's, which this skips)."""
     from bench import harness
 
-    return harness.run_cell("tiny", seed, seconds, False,
-                            t_start=time.perf_counter(),
-                            parts=parts or tiny_parts(), peaks=TINY_PEAKS,
-                            control=control)
+    result, verdict, _ = harness.run_cell(
+        "tiny", seed, seconds, False, t_start=time.perf_counter(),
+        parts=parts or tiny_parts(), peaks=TINY_PEAKS, control=control)
+    return result, verdict
 
 
 @pytest.fixture

@@ -93,3 +93,32 @@ def test_least_time_names_its_bound():
     assert cost.least_time(1, 819e9, peaks) == (1.0, "memory")
     with pytest.raises(KeyError):
         cost.load_peaks("cpu")
+
+
+@pytest.mark.parametrize("name, mlp_gb", [("qwen2.5-1.5b", 2.312),
+                                          ("stablelm-3b", 3.397)])
+def test_dense_scopes_count_their_own_matrices(name, mlp_gb):
+    from bench import harness
+
+    fam = harness.load_family("dense")
+    m = model(name)
+    ctx = [700, 900, 1100]
+    kv = cost.distinct_kv_tokens([(600, 3, 200)], 512)
+    fa, ba = fam.scope_cost(m, "attention", ctx, kv, {})
+    fm, bm = fam.scope_cost(m, "mlp", ctx, kv, {})
+    assert bm / 1e9 == pytest.approx(mlp_gb, abs=5e-4)
+    assert fm == 2 * 3 * bm // 2
+    # attention and mlp, the norms and the tables make the whole step
+    d, L, V = m["d_model"], m["n_layers"], m["vocab_size"]
+    f, b = fam.decode_cost(m, ctx, kv, {})
+    norms = (2 * L * d + d) * 2
+    tables = (V * d + (0 if m["tie_embeddings"] else 3 * d)) * 2
+    assert ba + bm + norms + tables == b
+    assert fa + fm + 3 * 2 * d * V == f
+    assert fam.scope_cost(m, "sampler", ctx, kv, {}) is None
+    assert fam.scope_cost(m, "mlp", [], 0, {}) is None
+    # the dense entry points are the family's
+    assert cost.decode_cost(m, ctx, kv) == (f, b)
+    assert cost.weight_bytes(m) == fam.weight_bytes(m)
+    assert cost.kv_bytes_per_token(m) == fam.kv_bytes_per_token(m)
+    assert cost.prefill_cost(m, 5, 7) == fam.prefill_cost(m, 5, 7, {})

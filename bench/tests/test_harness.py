@@ -5,6 +5,8 @@ import os
 import shutil
 import subprocess
 import sys
+import time
+from pathlib import Path
 from types import SimpleNamespace
 
 import numpy as np
@@ -12,7 +14,8 @@ import pytest
 
 from bench import generator as gen
 from bench import harness
-from bench.tests.conftest import DATA, ROOT
+from bench.tests.conftest import DATA, ROOT, TINY_PEAKS
+from repro.serving.telemetry import Gauge, Span
 
 BIG_SEED = 2 ** 31 + 123456789
 
@@ -69,7 +72,8 @@ def record(**kw):
                 steps=range(5, 9), step_spans={}, decode_spans={},
                 occupancy={4: 4, 5: 4, 6: 3, 7: 4, 8: 2, 9: 4}, ctx={},
                 kv_tokens={},
-                prefills={}, token_times={}, due={}, peaks={})
+                prefills={}, token_times={}, due={}, peaks={},
+                family=harness.load_family("dense"))
     base.update(kw)
     return harness.RunRecord(**base)
 
@@ -118,16 +122,26 @@ def test_record_counts_each_steps_rows_and_their_distinct_kv():
               ev("token", 1, 2), ev("token", 2, 2),
               ev("token", 1, 3), ev("token", 2, 3)]
     sched = SimpleNamespace(metrics=SimpleNamespace(records=[]))
+    spans = [Span("step", s - 0.5, s + 0.4, s) for s in (1, 2, 3)]
+    gauges = [Gauge("occupancy", 1.3, 4), Gauge("occupancy", 2.3, 8),
+              Gauge("free_blocks", 2.35, 90), Gauge("occupancy", 2.45, 7),
+              Gauge("occupancy", 4.0, 8)]
     rec = harness.record_from(
-        sched, SimpleNamespace(spans=[], events=events), m={}, n_slots=8,
-        setup_s=0.0, t0=0.0, t1=4.0, steps=range(1, 4), due={},
-        prompt_len_of={1: 40, 2: 48}, peaks={}, shared_prefix=32)
+        sched, SimpleNamespace(spans=spans, events=events, gauges=gauges),
+        m={}, n_slots=8, setup_s=0.0, t0=0.0, t1=4.0, steps=range(1, 4),
+        due={}, prompt_len_of={1: 40, 2: 48}, peaks={},
+        family=harness.load_family("dense"), shared_prefix=32)
     assert rec.ctx[3] == [43] * 4 + [50] * 4
     # the header once, each prompt's own 8 and 16 tokens once, and 3 and 2
     # tokens of each of the 4 samples
     assert rec.kv_tokens[3] == 32 + (8 + 4 * 3) + (16 + 4 * 2)
     assert rec.kv_tokens[1] == 40 + 4
     assert rec.prefills == {1: [(8, 32)], 2: [(16, 32)]}
+    # every span and gauge, and each step's gauges by name: a gauge
+    # between two steps' spans belongs to neither
+    assert rec.spans == spans and rec.gauges == gauges
+    assert rec.counters == {1: {"occupancy": 4},
+                            2: {"occupancy": 8, "free_blocks": 90}}
 
 
 @pytest.fixture
@@ -138,10 +152,23 @@ def copy_root(tmp_path):
     return tmp_path
 
 
+def tree_bytes(root):
+    return {p.relative_to(root): p.read_bytes()
+            for p in sorted(root.rglob("*")) if p.is_file()
+            and "__pycache__" not in p.parts}
+
+
 def test_new_config_traffic_and_metric_are_found_by_name(copy_root):
+    """A configuration with a model family of its own, its traffic, a
+    metric and a cell come in as new files and entries alone: a whole run
+    of the cell, its weights and check, and the roofline readers reach
+    the new family."""
     root = copy_root
+    before = tree_bytes(root)
     cfg = json.loads((DATA / "tiny.json").read_text())
+    cfg["family"] = "counted"
     (root / "bench/configs/tiny.json").write_text(json.dumps(cfg))
+    shutil.copy(DATA / "counted_family.py", root / "bench/families/counted.py")
     shutil.copy(DATA / "tiny-mix.json", root / "bench/traffic/tiny-mix.json")
     (root / "bench/metrics/steps_in_window.py").write_text(
         "def read(run):\n    return len(run.steps)\n")
@@ -165,6 +192,27 @@ def test_new_config_traffic_and_metric_are_found_by_name(copy_root):
         "output_tokens_per_s", "itl_p95_ms", "setup_s"}
     assert harness.load_reader("steps_in_window", root)(record()) == 4
     assert harness.load_loop(parts["mix"]["loop"]).Loop
+
+    family = parts["family"]
+    assert family is harness.load_family("counted", root)
+    result, _, rec = harness.run_cell(
+        "tiny.cell", 2 ** 31 + 99, 1.0, False, t_start=time.perf_counter(),
+        root=root, parts=parts, peaks=TINY_PEAKS)
+    assert result["correct"], result["check"]
+    assert rec.family is family
+    assert family.CALLS["layout"] == 1       # the weights
+    assert family.CALLS["hidden"] > 0        # the check's reference
+    assert family.CALLS["head"] == 1
+    # the device readers count the step's work through it
+    rec.trace = {"programs": {"jit__step_paged_impl": 1.0},
+                 "scopes": {"jit__step_paged_impl": {"attention": 0.5}},
+                 "program_calls": {"jit__step_paged_impl": 2}}
+    rec.traced = rec.steps
+    for name in ("decode_roofline", "step_mfu", "attention_roofline"):
+        assert harness.load_reader(name, root)(rec) > 0
+    assert family.CALLS["decode_cost"] > 0 and family.CALLS["scope_cost"] > 0
+    changed = {p for p, b in tree_bytes(root).items() if before.get(p) != b}
+    assert {p for p in changed if p in before} == {Path("BENCHMARK.json")}
 
 
 def test_the_cells_list_their_metrics():
@@ -200,3 +248,21 @@ def test_loop_keeps_slots_and_backlog_full():
     sched.metrics.completed_requests = 9
     loop.after_step(sched, lambda item, due: sent.append((item, due)), 2.0)
     assert len(sent) == 12 and sent[-1][1] == 2.0
+
+
+def test_model_config_applies_a_nested_group_onto_the_registrys():
+    from repro.configs.base import MoEConfig
+
+    config = {"name": "olmoe", "arch": "olmoe-1b-7b", "weight_dtype": "bfloat16",
+              "model": {"n_layers": 4, "d_model": 2048,
+                        "moe": {"n_experts": 64, "top_k": 8}}}
+    cfg = harness.model_config(config)
+    assert isinstance(cfg.moe, MoEConfig)
+    assert (cfg.moe.n_experts, cfg.moe.top_k, cfg.moe.expert_d_ff) == \
+        (64, 8, 1024)                   # the registry's width is kept
+    assert cfg.n_layers == 4 and cfg.param_dtype == "bfloat16"
+    hash(cfg)                           # jit takes it as a static argument
+    with pytest.raises(TypeError):      # a size the group does not hold
+        harness.model_config({**config, "model": {"moe": {"n_expert": 64}}})
+    with pytest.raises(ValueError, match="no moe group"):
+        harness.model_config({**config, "arch": "qwen2.5-1.5b"})

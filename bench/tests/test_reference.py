@@ -89,3 +89,63 @@ def test_gumbel_replay_is_the_samplers_draw():
     want = jax.random.categorical(key, x, axis=-1)
     got = jnp.argmax(x + jax.random.gumbel(key, x.shape, jnp.float32), axis=-1)
     assert (np.asarray(want) == np.asarray(got)).all()
+
+
+TINY_SEED = 2 ** 31 + 12345
+# recorded from the code before the model families existed: the weights
+# and the reference's logits of data/tiny.json at TINY_SEED are the same
+TINY_WEIGHTS_SHA256 = \
+    "6630da323fe3bcd48a6eb3146eecd46a05c87be41e2d539ada09036766fc7ca3"
+TINY_LOGITS = {  # [position, token] -> logit, then the sum of |logits|
+    "f32": ([0.15592080354690552, -0.1912640929222107, -0.2754184305667877,
+             -0.001193277072161436], 1411.7208251953125),
+    "fp8": ([0.15916940569877625, -0.18320320546627045, -0.2602327764034271,
+             -0.010415240190923214], 1406.80615234375),
+}
+
+
+def tiny_model():
+    import json
+
+    from bench.tests.conftest import DATA
+
+    return json.loads((DATA / "tiny.json").read_text())["model"]
+
+
+@pytest.mark.parametrize("entry", ["family", "dense_entry_point"])
+def test_dense_family_weights_are_the_same_from_the_same_seed(entry):
+    """The harness's path (the family's layout) and ``weights.make``."""
+    import hashlib
+
+    from bench import harness
+
+    m = tiny_model()
+    if entry == "family":
+        params = weights.from_layout(harness.load_family("dense").layout(m),
+                                     TINY_SEED, "bfloat16")
+    else:
+        params = weights.make(m, TINY_SEED, "bfloat16")
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        a = np.asarray(leaf)
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(str(a.dtype).encode())
+        h.update(str(a.shape).encode())
+        h.update(a.tobytes())
+    assert h.hexdigest() == TINY_WEIGHTS_SHA256
+
+
+@pytest.mark.parametrize("precision", ["f32", "fp8"])
+def test_dense_family_reference_logits_are_unchanged(precision):
+    from bench import harness
+
+    fam = harness.load_family("dense")
+    m = tiny_model()
+    params = weights.from_layout(fam.layout(m), TINY_SEED, "bfloat16")
+    seq = (np.arange(24) * 37 % m["vocab_size"]).astype(np.int32)
+    logits = np.asarray(fam.hidden(params, seq, m, precision)
+                        @ fam.head(params, precision).T)
+    values, total = TINY_LOGITS[precision]
+    got = logits[[0, 5, 11, 23], [3, 100, 250, 511]]
+    np.testing.assert_allclose(got, values, rtol=1e-6, atol=1e-7)
+    assert float(np.abs(logits).sum()) == pytest.approx(total, rel=1e-6)

@@ -13,7 +13,7 @@ from types import SimpleNamespace
 
 import pytest
 
-from bench import harness, trace_reduce, trace_run
+from bench import trace_reduce, trace_run
 from bench import trace_scopes as ts
 from bench.tests.conftest import DATA, TINY_PEAKS, tiny_parts
 from repro.serving.telemetry import Span
@@ -207,12 +207,12 @@ def test_recorded_scoped_fixture():
     assert old["idle_gaps"][0][0] == "bench.step_once"
 
 
-def _record(trace=None, spans=(), steps=range(0)):
-    return SimpleNamespace(trace=trace, spans=list(spans), steps=steps)
+def _record(trace=None, spans=(), steps=range(0), **kw):
+    return SimpleNamespace(trace=trace, spans=list(spans), steps=steps, **kw)
 
 
 def test_readers_of_the_scoped_trace_and_the_host_spans():
-    from bench.metrics import attention_ms, sampler_ms, step_host_ms
+    from bench.metrics import attention_ms, mlp_ms, sampler_ms, step_host_ms
 
     trace = {"scopes": {"jit__step_paged_impl": {"attention": 0.06,
                                                  "sampler": 0.3},
@@ -223,9 +223,11 @@ def test_readers_of_the_scoped_trace_and_the_host_spans():
                                "jit__prefill_cached_impl": 2}}
     assert attention_ms.read(_record(trace)) == pytest.approx(20.0)
     assert sampler_ms.read(_record(trace)) == pytest.approx(100.0)
-    # the accepted reduction has no scopes: the readers read nothing
+    # a reduction without scopes, or a decode program without the scope:
+    # the readers read nothing
     assert attention_ms.read(_record({"program_calls": {}})) is None
     assert attention_ms.read(_record(None)) is None
+    assert mlp_ms.read(_record(trace)) is None
     spans = [Span("step", 0.0, 0.200, 4), Span("sync", 0.010, 0.190, 4),
              Span("step", 0.200, 0.400, 5), Span("sync", 0.205, 0.395, 5),
              Span("step", 0.400, 0.600, 6)]
@@ -238,10 +240,12 @@ def test_trace_run_names_the_host_phases_on_the_cpu():
     """The tiny cell's untraced run through ``trace_run``: every step's
     phases, garbage collections and the gap between steps."""
     result, phases = trace_run.run(
-        "tiny", 2 ** 31 + 77, 1.0, False, tiny_parts(), harness, ts,
+        "tiny", 2 ** 31 + 77, 1.0, False, tiny_parts(),
         t_start=time.perf_counter(), peaks=TINY_PEAKS)
     assert result["correct"]
-    assert harness.trace_reduce is trace_reduce     # put back
+    # the cell's own metrics, nothing added at run time
+    assert set(result["metrics"]) == {"output_tokens_per_s", "itl_p95_ms",
+                                      "setup_s"}
     for name in trace_run.PHASES:
         assert phases[f"{name}_ms"] >= 0
     assert phases["step_ms"] >= phases["decode_ms"] >= phases["sync_ms"] > 0
@@ -249,3 +253,37 @@ def test_trace_run_names_the_host_phases_on_the_cpu():
         + phases["post_ms"]
     assert phases["gc_s"] >= phases["gc_max_s"] >= 0
     assert phases["step_gap_max_s"] > 0 and "step_gap_span" in phases
+
+
+def test_new_readers_read_the_recorded_scoped_fixture():
+    """The fixture's ``scoped_step`` stands for the decode program, its
+    ``alpha`` and ``beta`` scopes for attention and the MLP: each reader
+    reads them from the harness's reduction of the recorded trace."""
+    from bench.metrics import (attention_ms, attention_roofline, mlp_ms,
+                               mlp_roofline)
+
+    r = ts.reduce(ts.read(SCOPED))
+    step = next(p for p in r["scopes"] if "scoped_step" in p)
+    names = {"alpha": "attention", "beta": "mlp"}
+    runs = r["program_calls"][step]
+    trace = {**r, "scopes": {"jit__step_paged_impl": {
+        names.get(k, k): v for k, v in r["scopes"][step].items()}},
+        "program_calls": {"jit__step_paged_impl": runs}}
+    alpha, beta = r["scopes"][step]["alpha"], r["scopes"][step]["beta"]
+    assert runs >= 4 and alpha > 0 and beta > 0
+    # one traced step a run, whose scopes each need 1 ms at the roofline:
+    # attention bound by compute, the MLP by bandwidth
+    need = {"attention": (197e9, 1.0), "mlp": (1.0, 8.19e8)}
+    rec = _record(trace, traced=range(runs), ctx={s: [8] for s in range(runs)},
+                  kv_tokens={}, counters={}, m={},
+                  family=SimpleNamespace(
+                      scope_cost=lambda m, scope, *a: need.get(scope)),
+                  peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
+    assert attention_ms.read(rec) == pytest.approx(1e3 * alpha / runs)
+    assert mlp_ms.read(rec) == pytest.approx(1e3 * beta / runs)
+    assert attention_roofline.read(rec) == pytest.approx(
+        100 * runs * 1e-3 / alpha)
+    assert mlp_roofline.read(rec) == pytest.approx(100 * runs * 1e-3 / beta)
+    # a scope the family does not count reads nothing, not 0
+    need.clear()
+    assert attention_roofline.read(rec) is None

@@ -1,26 +1,18 @@
 #!/usr/bin/env python3
-"""Run one benchmark cell as ``bench/run.py`` does, and read the program's
-own names besides: each layer's device time from its named scope and
-what the scheduler's host phases took.
+"""Run one benchmark cell as ``bench/run.py`` does, and log besides what
+the result line leaves out: what the scheduler's host phases took and
+each program's device time by named scope.
 
     python3 bench/trace_run.py --workload <cell> --seed <n> --seconds <s> \\
         --trace <0|1> [--out <file.json>]
 
-The harness reduces its trace with ``bench/trace_reduce.py`` and gives its
-readers the tracer's ``step`` and ``decode`` spans alone.  This command
-runs the same cell with ``bench/trace_scopes.py`` in that reduction's
-place and every tracer span as the record's ``spans``, so that it also
-reports:
-
-* with ``--trace 1``, the metrics of :data:`PENDING` among the
-  per-layer ones;
-* in every run, a ``phases`` object on standard error (and in ``--out``):
-  each host phase's mean ms per step, garbage collection in the window
-  (``gc_s``, ``gc_max_s``), the longest gap between consecutive steps
-  with the span covering most of it, and with ``--trace 1`` each
-  program's ms per run by scope, the scopes' sum against the union of the
-  program's operations, the idle seconds by host phase, and the host
-  phases of the traced steps beside those of the steps before the trace.
+In every run it writes a ``phases`` object on standard error (and in
+``--out``): each host phase's mean ms per step, garbage collection in the
+window (``gc_s``, ``gc_max_s``), the longest gap between consecutive
+steps with the span covering most of it, and with ``--trace 1`` each
+program's ms per run by scope, the scopes' sum against the union of the
+program's operations, the idle seconds by host phase, and the host phases
+of the traced steps beside those of the steps before the trace.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits 2.
 """
@@ -38,14 +30,6 @@ from pathlib import Path  # noqa: E402
 ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-# per-layer metrics that need the scoped trace or the tracer's every span
-PENDING = [
-    {"name": "attention_ms", "unit": "ms"},
-    {"name": "mlp_ms", "unit": "ms"},
-    {"name": "lm_head_ms", "unit": "ms"},
-    {"name": "sampler_ms", "unit": "ms"},
-    {"name": "step_host_ms", "unit": "ms"},
-]
 PHASES = ("step", "admit", "prefill", "decode", "plan", "dispatch", "sync",
           "post", "gc")
 # a step's child phases, where the host keeps the device waiting
@@ -123,7 +107,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", type=Path)
     args = ap.parse_args(argv)
 
-    from bench import harness, trace_scopes
+    from bench import harness
 
     parts = harness.find_cell(harness.load_benchmark(), args.workload)
     import jax
@@ -136,7 +120,7 @@ def main(argv=None) -> int:
         return 2
     harness.enable_compile_cache()
     result, phases = run(args.workload, args.seed, args.seconds,
-                         bool(args.trace), parts, harness, trace_scopes)
+                         bool(args.trace), parts)
     harness.log("phases", json.dumps(phases))
     if args.out:
         args.out.parent.mkdir(parents=True, exist_ok=True)
@@ -146,30 +130,13 @@ def main(argv=None) -> int:
     return 0
 
 
-def run(name, seed, seconds, trace, parts, harness, trace_scopes, *,
-        t_start=T_START, **kw):
-    """``harness.run_cell`` with the scoped reduction, the tracer's every
-    span on the record and the pending metrics; returns the result line's
-    object and the ``phases`` object."""
-    kept = {}
-    record_from = harness.record_from
+def run(name, seed, seconds, trace, parts, *, t_start=T_START, **kw):
+    """``harness.run_cell``; returns the result line's object and the
+    ``phases`` object."""
+    from bench import harness
 
-    def record_with_spans(sched, tracer, **a):
-        rec = record_from(sched, tracer, **a)
-        rec.spans = list(tracer.spans)
-        kept["rec"] = rec
-        return rec
-
-    parts = {**parts, "per_layer": parts["per_layer"] + PENDING}
-    saved = harness.trace_reduce, harness.record_from
-    harness.trace_reduce, harness.record_from = trace_scopes, \
-        record_with_spans
-    try:
-        result, _ = harness.run_cell(name, seed, seconds, trace,
-                                     t_start=t_start, parts=parts, **kw)
-    finally:
-        harness.trace_reduce, harness.record_from = saved
-    rec = kept["rec"]
+    result, _, rec = harness.run_cell(name, seed, seconds, trace,
+                                      t_start=t_start, parts=parts, **kw)
     phases = host_phases(rec.spans, set(rec.steps), rec.t0, rec.t1)
     if rec.trace is not None:
         phases.update(device_phases(rec.trace))

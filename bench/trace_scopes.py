@@ -510,20 +510,26 @@ def reduce(raw: dict, top: int = 10) -> dict:
 
 # -- what the per-layer readers read ------------------------------------------
 
-# the scheduler's decode step, DecodeEngine._step_paged_impl (as in
-# bench/metrics/decode_roofline.py)
+# the scheduler's decode step, DecodeEngine._step_paged_impl (not the
+# forced or speculative variants, whose names end alike)
 DECODE = re.compile(r"(?<![A-Za-z])_step_paged_impl")
 
 
-def decode_scope_ms(run, scope: str):
-    """Device ms per decode step in ``scope``: the self time of the
-    decode program's operations under it over the program's runs in the
-    traced window; None without a scoped trace."""
+def decode_scope_s(run, scope: str):
+    """``(seconds, runs)``: the self time of the decode program's
+    operations under ``scope`` in the traced window, and the program's
+    runs there; None without a scoped trace, a run or the scope."""
     scopes = (run.trace or {}).get("scopes")
     if not scopes:
         return None
     progs = [p for p in scopes if DECODE.search(p)]
     runs = sum(run.trace["program_calls"].get(p, 0) for p in progs)
-    if not runs:
+    if not runs or not any(scope in scopes[p] for p in progs):
         return None
-    return 1e3 * sum(scopes[p].get(scope, 0.0) for p in progs) / runs
+    return sum(scopes[p].get(scope, 0.0) for p in progs), runs
+
+
+def decode_scope_ms(run, scope: str):
+    """Device ms per decode step in ``scope``."""
+    got = decode_scope_s(run, scope)
+    return None if got is None else 1e3 * got[0] / got[1]

@@ -1,9 +1,9 @@
 """Seeded random weights, made on the device in one jitted call.
 
-The layout is the one the served transformer reads (``embedding``,
-stacked ``layers``, ``final_norm``, ``lm_head`` when untied); the plain
-reference in ``bench/reference`` reads the same tree.  Weights are made in
-the type they are served in (bf16 for the published checkpoints).
+The tree and each leaf's shape and distribution are the model family's
+``layout`` (``bench/families``), the tree the served program reads; the
+family's reference reads the same tree.  Weights are made in the type
+they are served in (bf16 for the published checkpoints).
 """
 from __future__ import annotations
 
@@ -11,42 +11,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-BIAS_STD = 0.5     # qkv biases: large enough that a dropped bias shows
-NORM_STD = 0.1     # norm scales around 1
-EMBED_STD = 0.02
+from bench.families import dense
 
 
 def layout(m: dict) -> dict:
-    """Leaves are ``(shape, mean, std)``."""
-    d, L, f, V = m["d_model"], m["n_layers"], m["d_ff"], m["vocab_size"]
-    hq, hkv, hd = m["n_heads"], m["n_kv_heads"], m["head_dim"]
-
-    def lin(i, o, bias=False):
-        leaf = {"w": ((L, i, o), 0.0, i ** -0.5)}
-        if bias:
-            leaf["b"] = ((L, o), 0.0, BIAS_STD)
-        return leaf
-
-    def norm(*lead):
-        return {"scale": ((*lead, d), 1.0, NORM_STD)}
-
-    qkv_bias = bool(m.get("qkv_bias", False))
-    tree = {
-        "embedding": {"table": ((V, d), 0.0, EMBED_STD)},
-        "layers": {
-            "attn_norm": norm(L),
-            "attn": {"wq": lin(d, hq * hd, qkv_bias),
-                     "wk": lin(d, hkv * hd, qkv_bias),
-                     "wv": lin(d, hkv * hd, qkv_bias),
-                     "wo": lin(hq * hd, d)},
-            "ffn_norm": norm(L),
-            "ffn": {"gate": lin(d, f), "up": lin(d, f), "down": lin(f, d)},
-        },
-        "final_norm": norm(),
-    }
-    if not m.get("tie_embeddings", False):
-        tree["lm_head"] = {"table": ((V, d), 0.0, EMBED_STD)}
-    return tree
+    """The dense family's leaves ``(shape, mean, std)``."""
+    return dense.layout(m)
 
 
 def _is_leaf(x) -> bool:
@@ -59,8 +29,10 @@ def key_data(seed: int, which: int = 0) -> np.ndarray:
     return rng.integers(0, 2 ** 32, size=2, dtype=np.uint32)
 
 
-def make(m: dict, seed: int, dtype: str = "bfloat16") -> dict:
-    leaves, treedef = jax.tree.flatten(layout(m), is_leaf=_is_leaf)
+def from_layout(tree: dict, seed: int, dtype: str) -> dict:
+    """Every leaf ``(shape, mean, std)`` of a family's ``layout(m)`` drawn
+    from ``seed`` on the device, in ``dtype``."""
+    leaves, treedef = jax.tree.flatten(tree, is_leaf=_is_leaf)
     dt = jnp.dtype(dtype)
 
     @jax.jit
@@ -73,6 +45,12 @@ def make(m: dict, seed: int, dtype: str = "bfloat16") -> dict:
     return build(jnp.asarray(key_data(seed)))
 
 
+def make(m: dict, seed: int, dtype: str = "bfloat16") -> dict:
+    """The dense family's weights."""
+    return from_layout(layout(m), seed, dtype)
+
+
 def n_params(m: dict) -> int:
+    """Parameters of the dense family's layout."""
     leaves = jax.tree.leaves(layout(m), is_leaf=_is_leaf)
     return int(sum(np.prod(shape) for shape, _, _ in leaves))
